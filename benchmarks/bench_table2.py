@@ -2,8 +2,7 @@
 accuracy/speedup on the synthetic difficulty-structured dataset.
 
 Small-scale (CPU) variant of examples/paper_reproduction.py so that
-``python -m benchmarks.run`` is self-contained; the full-scale numbers live
-in results/repro_c10.json (EXPERIMENTS.md §Paper).
+``python -m benchmarks.run`` is self-contained.
 """
 import time
 

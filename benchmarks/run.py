@@ -50,6 +50,8 @@ def main() -> None:
                     help="comma-separated bench names to run")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_table2, bench_fig3, bench_fig4,
                             bench_llm_cascade, bench_kernels,
                             bench_ablation, bench_autotune, bench_fleet,

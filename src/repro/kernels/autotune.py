@@ -1,7 +1,7 @@
 """Per-arch / per-backend Pallas kernel tile autotuner.
 
 The kernels ship with hand-picked default tiles (``tk=512`` KV tiles for
-decode attention, ``(8, 2048)`` logits tiles for the exit-update family,
+decode attention, ``(8, 1024)`` logits tiles for the exit-update family,
 ...).  Whether those win depends on the execution backend: the Pallas
 *interpreter* (CPU CI) pays per-grid-cell Python dispatch, so it wants
 few large tiles, while compiled Mosaic on a TPU wants tiles sized to VMEM
@@ -57,10 +57,12 @@ DEFAULT_TILES: Dict[str, Dict[str, Any]] = {
     "flash_attention": {"tq": 128, "tk": 128},
     "rmsnorm": {"rt": 8},
     "confidence": {"bt": 8, "vt": 2048},
-    "exit_update": {"bt": 8, "vt": 2048},
+    "exit_update": {"bt": 8, "vt": 1024},
     # matches exit_update: same (bt, vt) ⇒ same streaming accumulation
-    # order ⇒ bit-identical confidences between the fused and mega paths
-    "megakernel": {"bt": 8, "vt": 2048},
+    # order ⇒ bit-identical confidences between the fused and mega paths.
+    # vt = 1024 because a (2048, 2048) bf16 head tile, double-buffered,
+    # overflows the megakernel's VMEM at d = 2048 on a TPU v5e
+    "megakernel": {"bt": 8, "vt": 1024},
     "paged_gather": {"impl": "pallas"},
 }
 

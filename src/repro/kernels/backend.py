@@ -1,11 +1,8 @@
 """Kernel execution-backend policy: when do Pallas kernels run interpreted?
 
-The kernels were seeded with ``interpret=True`` hard defaults (this repo's CI
-is CPU-only), which meant a real TPU deployment that forgot to flip an env
-var silently ran every kernel through the Pallas *interpreter* — orders of
-magnitude slower than the compiled path, with no error to notice.  This
-module makes the default backend-aware and keeps exactly one precedence
-order for overrides:
+Mosaic lowering needs a TPU, so off-TPU (CPU CI) every kernel runs through
+the Pallas interpreter, and on a TPU every kernel runs compiled.  One
+precedence order decides it:
 
 1. an explicit non-None override — either an ``interpret=`` argument at a
    kernel call site (tests pin interpreter semantics this way) or
@@ -14,12 +11,12 @@ order for overrides:
 2. the ``REPRO_KERNEL_INTERPRET`` environment variable ("0" forces
    compiled, anything else forces interpreted) — consulted only when no
    explicit override was given,
-3. auto-detection: interpret only off-TPU (CPU/GPU hosts run the
-   interpreter because Mosaic lowering needs a TPU; a TPU backend runs
-   compiled).
+3. auto-detection: interpret only off-TPU.
 
-Forcing the interpreter ON a TPU backend is almost always a mistake, so that
-combination logs a one-time warning instead of staying silent.
+Forcing the interpreter on a TPU backend raises: the interpreter is orders
+of magnitude slower than Mosaic, and a run that silently took it would
+report interpreter numbers as chip numbers.  A backend that fails to
+initialise raises too, rather than being read as "not a TPU".
 """
 from __future__ import annotations
 
@@ -28,32 +25,12 @@ from typing import Optional
 
 import jax
 
-from repro.utils import get_logger
-
-log = get_logger("kernels.backend")
-
 _ENV = "REPRO_KERNEL_INTERPRET"
-_warned_interpret_on_tpu = False
-
-
-def reset_backend_warnings() -> None:
-    """Re-arm the one-time interpret-on-TPU warning.
-
-    The warning latch is a module global, so a test that legitimately
-    forces interpret mode on a TPU backend would otherwise silence the
-    warning for every later test in the process.  ``tests/conftest.py``
-    calls this between tests; production code never needs to.
-    """
-    global _warned_interpret_on_tpu
-    _warned_interpret_on_tpu = False
 
 
 def on_tpu() -> bool:
     """True when the default jax backend is a TPU."""
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # pragma: no cover - backend init failure
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def resolve_interpret(override: Optional[bool] = None) -> bool:
@@ -61,18 +38,17 @@ def resolve_interpret(override: Optional[bool] = None) -> bool:
 
     ``override`` is a call-site / config override (``None`` = no opinion).
     Precedence: explicit override > ``REPRO_KERNEL_INTERPRET`` env var >
-    backend auto-detection (interpret iff not on TPU).
+    backend auto-detection (interpret iff not on TPU).  Raises
+    ``ValueError`` when the result would run the interpreter on a TPU.
     """
-    global _warned_interpret_on_tpu
     if override is None and _ENV in os.environ:
         override = os.environ[_ENV] != "0"
     if override is None:
         return not on_tpu()
     override = bool(override)
-    if override and on_tpu() and not _warned_interpret_on_tpu:
-        _warned_interpret_on_tpu = True
-        log.warning(
-            "Pallas kernels forced to interpret mode ON a TPU backend "
-            "(override/%s) — this runs the interpreter, not Mosaic; "
-            "expect orders-of-magnitude slowdown", _ENV)
+    if override and on_tpu():
+        raise ValueError(
+            f"Pallas kernels forced to interpret mode on a TPU backend "
+            f"(kernel_interpret / interpret= / {_ENV}): the TPU runs the "
+            f"Mosaic-compiled kernels; drop the override")
     return override

@@ -18,7 +18,10 @@ call once per cohort (``dst = cohort_scatter(dst, part, c, C)``) rebuilds the
 slab with C cohort-sized writes instead of one B-sized concat.
 
 ``c`` and ``C`` are Python ints (the cohort loop in ``_mixed`` is unrolled),
-so the block index maps are static — no dynamic-slice lowering.
+so the block index maps are static — no dynamic-slice lowering.  The grid
+walks (layer, cohort row, row tile) over a ``(L, B, R/128, 128)`` view, so
+a cohort of any row count (lane batch 8 in two cohorts is 4 rows) meets
+the TPU's (8, 128) block tiling rule.
 
 Semantics are bit-identical to the concat (pinned by tests); only the memory
 traffic changes.  Non-array-friendly leaves (cohort axis missing, or a
@@ -27,6 +30,7 @@ trailing extent the TPU layout can't partial-write) fall back to
 """
 from __future__ import annotations
 
+import math
 from functools import partial
 
 import jax
@@ -36,7 +40,14 @@ from jax.experimental import pallas as pl
 from repro.kernels.backend import resolve_interpret
 
 
+LANES = 128
+# rows of LANES elements per block: 2048 x 128 bf16 = 512 KiB, so the
+# double-buffered source and destination blocks stay far inside VMEM
+MAX_BLOCK_ROWS = 2048
+
+
 def _scatter_kernel(dst_ref, src_ref, out_ref):
+    del dst_ref  # aliased to out_ref; never read
     out_ref[...] = src_ref[...]
 
 
@@ -44,24 +55,27 @@ def _scatter_kernel(dst_ref, src_ref, out_ref):
 def _scatter(dst, src, c: int, C: int, interpret: bool):
     L, B = dst.shape[0], dst.shape[1]
     Bc = B // C
-    rest = dst.shape[2:]
-    R = 1
-    for r in rest:
-        R *= r
-    d3 = dst.reshape(L, B, R)
-    s3 = src.reshape(L, Bc, R)
+    R = math.prod(dst.shape[2:])
+    # each batch row's trailing extent viewed as (rows, lanes): the block's
+    # last two dims are then (8k, 128) or full, whatever Bc is
+    lanes = LANES if R % LANES == 0 else R
+    rows = R // lanes
+    tr = math.gcd(rows, MAX_BLOCK_ROWS)
+    if tr % 8:
+        tr = rows
+    d4 = dst.reshape(L, B, rows, lanes)
+    s4 = src.reshape(L, Bc, rows, lanes)
+    blk = (1, 1, tr, lanes)
+    dst_spec = pl.BlockSpec(blk, lambda l, b, r: (l, c * Bc + b, r, 0))
     out = pl.pallas_call(
         _scatter_kernel,
-        grid=(L,),
-        in_specs=[
-            pl.BlockSpec((1, Bc, R), lambda l, _c=c: (l, _c, 0)),
-            pl.BlockSpec((1, Bc, R), lambda l: (l, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, Bc, R), lambda l, _c=c: (l, _c, 0)),
-        out_shape=jax.ShapeDtypeStruct(d3.shape, d3.dtype),
+        grid=(L, Bc, rows // tr),
+        in_specs=[dst_spec, pl.BlockSpec(blk, lambda l, b, r: (l, b, r, 0))],
+        out_specs=dst_spec,
+        out_shape=jax.ShapeDtypeStruct(d4.shape, d4.dtype),
         input_output_aliases={0: 0},
         interpret=interpret,
-    )(d3, s3)
+    )(d4, s4)
     return out.reshape(dst.shape)
 
 
@@ -78,12 +92,10 @@ def cohort_scatter(dst, src, c: int, C: int, *, interpret=None):
         lo = c * (dst.shape[1] // C) if dst.ndim >= 2 else 0
         return dst.at[:, lo:lo + src.shape[1]].set(src)
     Bc = dst.shape[1] // C
-    R = 1
-    for r in dst.shape[2:]:
-        R *= r
+    R = math.prod(dst.shape[2:])
     # compiled TPU lowering needs a lane-aligned trailing extent for a
     # partial write; oddball leaves take the plain XLA scatter instead
-    if not interpret and (R % 128 != 0 or dst.dtype == jnp.bool_):
+    if not interpret and (R % LANES != 0 or dst.dtype == jnp.bool_):
         return dst.at[:, c * Bc:(c + 1) * Bc].set(src)
     if dst.dtype == jnp.bool_:
         out = _scatter(dst.astype(jnp.int8), src.astype(jnp.int8), c, C,

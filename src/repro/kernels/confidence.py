@@ -7,7 +7,9 @@ running (max, Σexp, argmax) per row — O(B) output, one HBM read of the
 logits, zero intermediate HBM traffic.
 
 Grid: (B/Bt, V/Vt), vocab axis innermost so the running scratch accumulates
-across the contraction.  Tiles are MXU/VPU aligned (Vt multiple of 128).
+across the contraction.  Tiles are MXU/VPU aligned (Vt multiple of 128);
+per-row results ride as (Bt, 1) column blocks (the TPU tiling rule refuses
+a rank-1 (Bt,) block of a longer vector).
 """
 from __future__ import annotations
 
@@ -33,12 +35,13 @@ def _conf_kernel(x_ref, idx_ref, conf_ref, m_s, l_s, a_s, *, n_vtiles, vt):
         a_s[...] = jnp.zeros_like(a_s[...])
 
     x = x_ref[...].astype(jnp.float32)              # (Bt, Vt)
-    tile_max = jnp.max(x, axis=-1)                  # (Bt,)
-    tile_arg = jnp.argmax(x, axis=-1).astype(jnp.int32) + j * vt
+    tile_max = jnp.max(x, axis=-1, keepdims=True)   # (Bt, 1)
+    tile_arg = (jnp.argmax(x, axis=-1, keepdims=True).astype(jnp.int32)
+                + j * vt)
     m_old = m_s[...]
     m_new = jnp.maximum(m_old, tile_max)
     l_s[...] = (l_s[...] * jnp.exp(m_old - m_new)
-                + jnp.sum(jnp.exp(x - m_new[:, None]), axis=-1))
+                + jnp.sum(jnp.exp(x - m_new), axis=-1, keepdims=True))
     a_s[...] = jnp.where(tile_max > m_old, tile_arg, a_s[...])
     m_s[...] = m_new
 
@@ -73,13 +76,13 @@ def _confidence(logits, *, bt, vt, interpret):
         kernel,
         grid=(Bp // bt, n_vtiles),
         in_specs=[pl.BlockSpec((bt, vt), lambda i, j: (i, j))],
-        out_specs=[pl.BlockSpec((bt,), lambda i, j: (i,)),
-                   pl.BlockSpec((bt,), lambda i, j: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((Bp,), jnp.int32),
-                   jax.ShapeDtypeStruct((Bp,), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((bt,), jnp.float32),
-                        pltpu.VMEM((bt,), jnp.float32),
-                        pltpu.VMEM((bt,), jnp.int32)],
+        out_specs=[pl.BlockSpec((bt, 1), lambda i, j: (i, 0)),
+                   pl.BlockSpec((bt, 1), lambda i, j: (i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((Bp, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((Bp, 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bt, 1), jnp.float32),
+                        pltpu.VMEM((bt, 1), jnp.float32),
+                        pltpu.VMEM((bt, 1), jnp.int32)],
         interpret=interpret,
     )(x)
-    return idx[:B], conf[:B]
+    return idx[:B, 0], conf[:B, 0]

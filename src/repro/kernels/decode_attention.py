@@ -21,7 +21,9 @@ Layout: q (B, KV, qpk, hd); k, v (B, KV, W, hd); kpos (W,) int32 — or
 (B, W) for the paged cache layout's per-slot position rings (the lane-wide
 (W,) vector is broadcast; the masking arithmetic per row is unchanged, so
 dense calls are bit-identical to the 1-D operand); t scalar; live (B,)
-int32.
+int32.  ``t`` and ``live`` are scalar-prefetch operands (SMEM), and kpos
+streams as (1, 1, Tk) blocks of a (B, 1, W) view, so every block obeys the
+TPU's (8, 128) tiling rule at any B.
 """
 from __future__ import annotations
 
@@ -50,19 +52,19 @@ def _decode_kernel(t_ref, live_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
 
     # exit mask: dead slots skip the whole tile's compute (their scratch
     # stays zero, so the final write below emits an all-zero row)
-    @pl.when(live_ref[0] != 0)
+    @pl.when(live_ref[pl.program_id(0)] != 0)
     def _tile():
         t = t_ref[0]
         q = q_ref[0, 0].astype(jnp.float32)                # (qpk, hd)
         k = k_ref[0, 0].astype(jnp.float32)                # (Tk, hd)
         v = v_ref[0, 0].astype(jnp.float32)
-        kpos = kpos_ref[0]                                 # (Tk,)
+        kpos = kpos_ref[0]                                 # (1, Tk)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         mask = (kpos >= 0) & (kpos <= t)
         if window:
             mask &= kpos > t - window
-        s = jnp.where(mask[None, :], s, NEG)
+        s = jnp.where(mask, s, NEG)
         m_old = m_s[...]
         m_new = jnp.maximum(m_old, jnp.max(s, axis=-1))
         p = jnp.exp(s - m_new[:, None])
@@ -117,22 +119,27 @@ def _decode_attention(q, k_cache, v_cache, t, kpos, live, *, window, tk,
             else jnp.asarray(live).astype(jnp.int32))
     kernel = functools.partial(_decode_kernel, tk=tk, n_ktiles=n_ktiles,
                                window=window, scale=scale)
-    out = pl.pallas_call(
-        kernel,
+    # index maps take the grid indices, then the prefetched (t, live) refs
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
         grid=(B, KV, n_ktiles),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, ik: (0,)),
-            pl.BlockSpec((1,), lambda b, h, ik: (b,)),
-            pl.BlockSpec((1, 1, qpk, hd), lambda b, h, ik: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, tk, hd), lambda b, h, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, tk, hd), lambda b, h, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, tk), lambda b, h, ik: (b, ik)),
+            pl.BlockSpec((1, 1, qpk, hd), lambda b, h, ik, *_: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, tk, hd), lambda b, h, ik, *_: (b, h, ik, 0)),
+            pl.BlockSpec((1, 1, tk, hd), lambda b, h, ik, *_: (b, h, ik, 0)),
+            pl.BlockSpec((1, 1, tk), lambda b, h, ik, *_: (b, 0, ik)),
         ],
-        out_specs=pl.BlockSpec((1, 1, qpk, hd), lambda b, h, ik: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KV, qpk, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, qpk, hd),
+                               lambda b, h, ik, *_: (b, h, 0, 0)),
         scratch_shapes=[pltpu.VMEM((qpk, hd), jnp.float32),
                         pltpu.VMEM((qpk,), jnp.float32),
                         pltpu.VMEM((qpk,), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, qpk, hd), q.dtype),
         interpret=interpret,
-    )(jnp.asarray(t, jnp.int32).reshape(1), live, q, k_cache, v_cache, kpos)
+    )(jnp.asarray(t, jnp.int32).reshape(1), live, q, k_cache, v_cache,
+      kpos[:, None, :])
     return out

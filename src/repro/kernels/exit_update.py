@@ -30,7 +30,10 @@ cond structure in :meth:`repro.core.exec.StagedExecutor.decode_step` can
 know.
 
 Grid: (B/Bt, V/Vt), vocab axis innermost.  All (B,) carry vectors ride as
-(Bt,) blocks revisited every vocab tile and written once at the last.
+(B, 1) columns in (Bt, 1) blocks, revisited every vocab tile and written
+once at the last: a rank-1 (Bt,) block of a longer vector breaks the TPU's
+(8, 128) block tiling rule, while a (Bt, 1) block spans the full minor dim.
+A live threshold rides in SMEM.
 """
 from __future__ import annotations
 
@@ -44,6 +47,9 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.backend import resolve_interpret
 
 NEG = -1e30
+# answered' pred' exit' conf' streak' ema'
+CARRY_DTYPES = (jnp.int32, jnp.int32, jnp.int32, jnp.float32, jnp.int32,
+                jnp.float32)
 
 
 def _exit_update_kernel(*refs, n_vtiles, vt, threshold, m, n_components,
@@ -66,12 +72,13 @@ def _exit_update_kernel(*refs, n_vtiles, vt, threshold, m, n_components,
         a_s[...] = jnp.zeros_like(a_s[...])
 
     x = x_ref[...].astype(jnp.float32)              # (Bt, Vt)
-    tile_max = jnp.max(x, axis=-1)                  # (Bt,)
-    tile_arg = jnp.argmax(x, axis=-1).astype(jnp.int32) + j * vt
+    tile_max = jnp.max(x, axis=-1, keepdims=True)   # (Bt, 1)
+    tile_arg = (jnp.argmax(x, axis=-1, keepdims=True).astype(jnp.int32)
+                + j * vt)
     m_old = m_s[...]
     m_new = jnp.maximum(m_old, tile_max)
     l_s[...] = (l_s[...] * jnp.exp(m_old - m_new)
-                + jnp.sum(jnp.exp(x - m_new[:, None]), axis=-1))
+                + jnp.sum(jnp.exp(x - m_new), axis=-1, keepdims=True))
     a_s[...] = jnp.where(tile_max > m_old, tile_arg, a_s[...])
     m_s[...] = m_new
 
@@ -124,7 +131,7 @@ def _exit_update_kernel(*refs, n_vtiles, vt, threshold, m, n_components,
 def exit_update(logits, answered, pred, exit_idx, conf, streak, ema, active,
                 *, threshold, m: int, n_components: int,
                 patience_k: int = 0, ema_decay: float = 0.0,
-                tel_bins: int = 0, bt: int = 8, vt: int = 2048,
+                tel_bins: int = 0, bt: int = 8, vt: int = 1024,
                 interpret: "bool | None" = None):
     """One fused component step of the exit-decision scan.
 
@@ -180,8 +187,7 @@ def _exit_update(th_arr, logits, answered, pred, exit_idx, conf, streak,
             jnp.asarray(streak).astype(jnp.int32),
             jnp.asarray(ema).astype(jnp.float32),
             jnp.asarray(active).astype(jnp.int32)]
-    if padB:
-        vecs = [jnp.pad(v, (0, padB)) for v in vecs]
+    vecs = [jnp.pad(v, (0, padB))[:, None] for v in vecs]
     Bp, Vp = x.shape
     n_vtiles = Vp // vt
     kernel = functools.partial(
@@ -189,19 +195,14 @@ def _exit_update(th_arr, logits, answered, pred, exit_idx, conf, streak,
         threshold=threshold, m=int(m),
         n_components=int(n_components), patience_k=int(patience_k),
         ema_decay=float(ema_decay), dynamic=dynamic, tel_bins=tel_bins)
-    vec_spec = pl.BlockSpec((bt,), lambda i, j: (i,))
-    in_specs = ([pl.BlockSpec((1,), lambda i, j: (0,))] if dynamic else [])
+    vec_spec = pl.BlockSpec((bt, 1), lambda i, j: (i, 0))
+    in_specs = ([pl.BlockSpec(memory_space=pltpu.SMEM)] if dynamic else [])
     in_specs += [pl.BlockSpec((bt, vt), lambda i, j: (i, j))]
     in_specs += [vec_spec] * 7
     out_specs = [vec_spec] * (7 if tel_bins else 6)
-    out_shape = [jax.ShapeDtypeStruct((Bp,), jnp.int32),
-                 jax.ShapeDtypeStruct((Bp,), jnp.int32),
-                 jax.ShapeDtypeStruct((Bp,), jnp.int32),
-                 jax.ShapeDtypeStruct((Bp,), jnp.float32),
-                 jax.ShapeDtypeStruct((Bp,), jnp.int32),
-                 jax.ShapeDtypeStruct((Bp,), jnp.float32)]
+    out_shape = [jax.ShapeDtypeStruct((Bp, 1), dt) for dt in CARRY_DTYPES]
     if tel_bins:
-        out_shape += [jax.ShapeDtypeStruct((Bp,), jnp.int32)]
+        out_shape += [jax.ShapeDtypeStruct((Bp, 1), jnp.int32)]
     args = ([th_arr] if dynamic else []) + [x] + vecs
     outs = pl.pallas_call(
         kernel,
@@ -209,11 +210,11 @@ def _exit_update(th_arr, logits, answered, pred, exit_idx, conf, streak,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((bt,), jnp.float32),
-                        pltpu.VMEM((bt,), jnp.float32),
-                        pltpu.VMEM((bt,), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((bt, 1), jnp.float32),
+                        pltpu.VMEM((bt, 1), jnp.float32),
+                        pltpu.VMEM((bt, 1), jnp.int32)],
         interpret=interpret,
     )(*args)
-    outs = [o[:B] for o in outs]
+    outs = [o[:B, 0] for o in outs]
     outs[0] = outs[0].astype(bool)
     return tuple(outs)

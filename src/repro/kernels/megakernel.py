@@ -46,15 +46,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.backend import resolve_interpret
+from repro.kernels.exit_update import CARRY_DTYPES
 
 NEG = -1e30
 
 
 def _megakernel(*refs, n_vtiles, vt, V, threshold, m, n_components,
                 patience_k, ema_decay, dynamic, tel_bins, eps, lowp):
-    # ref layout: [th_ref?] x w head live | ans pred exit conf streak ema
-    #             act | outs (6 or 7) | scratch: m l a xn
+    # ref layout: blk [th_ref?] x w head live | ans pred exit conf streak
+    #             ema act | outs (6 or 7) | scratch: m l a xn
     refs = list(refs)
+    blk_ref = refs.pop(0)
     th_ref = refs.pop(0) if dynamic else None
     (x_ref, w_ref, head_ref, live_ref, ans_ref, pred_ref, exit_ref,
      conf_ref, streak_ref, ema_ref, act_ref) = refs[:11]
@@ -69,7 +71,9 @@ def _megakernel(*refs, n_vtiles, vt, V, threshold, m, n_components,
         l_s[...] = jnp.zeros_like(l_s[...])
         a_s[...] = jnp.zeros_like(a_s[...])
 
-    blk_live = jnp.any(live_ref[...] != 0)
+    # any live row in this Bt block (a scalar from SMEM, not a vector
+    # reduction: pl.when needs a scalar predicate)
+    blk_live = blk_ref[pl.program_id(0)] != 0
 
     @pl.when(jnp.logical_and(blk_live, j == 0))
     def _norm():
@@ -91,12 +95,13 @@ def _megakernel(*refs, n_vtiles, vt, V, threshold, m, n_components,
         # vocab pad columns (zero head columns) must never win the max
         col = j * vt + jax.lax.broadcasted_iota(jnp.int32, lt.shape, 1)
         lt = jnp.where(col < V, lt, NEG)
-        tile_max = jnp.max(lt, axis=-1)                 # (Bt,)
-        tile_arg = jnp.argmax(lt, axis=-1).astype(jnp.int32) + j * vt
+        tile_max = jnp.max(lt, axis=-1, keepdims=True)  # (Bt, 1)
+        tile_arg = (jnp.argmax(lt, axis=-1, keepdims=True)
+                    .astype(jnp.int32) + j * vt)
         m_old = m_s[...]
         m_new = jnp.maximum(m_old, tile_max)
         l_s[...] = (l_s[...] * jnp.exp(m_old - m_new)
-                    + jnp.sum(jnp.exp(lt - m_new[:, None]), axis=-1))
+                    + jnp.sum(jnp.exp(lt - m_new), axis=-1, keepdims=True))
         a_s[...] = jnp.where(tile_max > m_old, tile_arg, a_s[...])
         m_s[...] = m_new
 
@@ -149,7 +154,7 @@ def exit_head_update(h, norm_w, head, answered, pred, exit_idx, conf,
                      streak, ema, active, *, threshold, m: int,
                      n_components: int, patience_k: int = 0,
                      ema_decay: float = 0.0, tel_bins: int = 0, live=None,
-                     eps: float = 1e-5, bt: int = 8, vt: int = 2048,
+                     eps: float = 1e-5, bt: int = 8, vt: int = 1024,
                      interpret: "bool | None" = None):
     """One fused exit-head component step: rmsnorm(h) @ head streamed over
     vocab tiles into the exit-update scan.
@@ -202,9 +207,9 @@ def _exit_head_update(th_arr, h, norm_w, head, answered, pred, exit_idx,
             jnp.asarray(streak).astype(jnp.int32),
             jnp.asarray(ema).astype(jnp.float32),
             jnp.asarray(active).astype(jnp.int32)]
-    if padB:
-        vecs = [jnp.pad(v, (0, padB)) for v in vecs]
+    vecs = [jnp.pad(v, (0, padB))[:, None] for v in vecs]
     Bp = B + padB
+    blk = jnp.max(vecs[0].reshape(Bp // bt, bt), axis=1)
     n_vtiles = (V + padV) // vt
     kernel = functools.partial(
         _megakernel, n_vtiles=n_vtiles, vt=vt, V=V, threshold=threshold,
@@ -212,34 +217,30 @@ def _exit_head_update(th_arr, h, norm_w, head, answered, pred, exit_idx,
         patience_k=int(patience_k), ema_decay=float(ema_decay),
         dynamic=dynamic, tel_bins=tel_bins, eps=eps,
         lowp=(h.dtype != jnp.float32))
-    vec_spec = pl.BlockSpec((bt,), lambda i, j: (i,))
-    in_specs = ([pl.BlockSpec((1,), lambda i, j: (0,))] if dynamic else [])
+    vec_spec = pl.BlockSpec((bt, 1), lambda i, j: (i, 0))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    in_specs = [smem] + ([smem] if dynamic else [])
     in_specs += [pl.BlockSpec((bt, d), lambda i, j: (i, 0)),
                  pl.BlockSpec((d,), lambda i, j: (0,)),
                  pl.BlockSpec((d, vt), lambda i, j: (0, j))]
     in_specs += [vec_spec] * 8
     out_specs = [vec_spec] * (7 if tel_bins else 6)
-    out_shape = [jax.ShapeDtypeStruct((Bp,), jnp.int32),
-                 jax.ShapeDtypeStruct((Bp,), jnp.int32),
-                 jax.ShapeDtypeStruct((Bp,), jnp.int32),
-                 jax.ShapeDtypeStruct((Bp,), jnp.float32),
-                 jax.ShapeDtypeStruct((Bp,), jnp.int32),
-                 jax.ShapeDtypeStruct((Bp,), jnp.float32)]
+    out_shape = [jax.ShapeDtypeStruct((Bp, 1), dt) for dt in CARRY_DTYPES]
     if tel_bins:
-        out_shape += [jax.ShapeDtypeStruct((Bp,), jnp.int32)]
-    args = ([th_arr] if dynamic else []) + [x, norm_w, hd] + vecs
+        out_shape += [jax.ShapeDtypeStruct((Bp, 1), jnp.int32)]
+    args = [blk] + ([th_arr] if dynamic else []) + [x, norm_w, hd] + vecs
     outs = pl.pallas_call(
         kernel,
         grid=(Bp // bt, n_vtiles),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((bt,), jnp.float32),
-                        pltpu.VMEM((bt,), jnp.float32),
-                        pltpu.VMEM((bt,), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((bt, 1), jnp.float32),
+                        pltpu.VMEM((bt, 1), jnp.float32),
+                        pltpu.VMEM((bt, 1), jnp.int32),
                         pltpu.VMEM((bt, d), h.dtype)],
         interpret=interpret,
     )(*args)
-    outs = [o[:B] for o in outs]
+    outs = [o[:B, 0] for o in outs]
     outs[0] = outs[0].astype(bool)
     return tuple(outs)

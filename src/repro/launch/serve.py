@@ -2,6 +2,9 @@
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2.5-3b --smoke \
         --requests 8 --max-new 8 --threshold 0.5
+
+Without ``--smoke`` the arch runs at its published widths (qwen2.5-3b fits
+one 16 GB TPU v5e chip: 6.8 GB of bf16 weights).
 """
 from __future__ import annotations
 
@@ -12,6 +15,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.serving import CascadeServingEngine, Request
 from repro.utils import get_logger
@@ -121,6 +125,7 @@ def main():
                          "trace-event JSON (load in Perfetto or "
                          "chrome://tracing); implies --obs")
     args = ap.parse_args()
+    enable_compile_cache()
     if (args.metrics_port is not None or args.flight_dump is not None
             or args.trace_out):
         args.obs = True
@@ -156,7 +161,9 @@ def main():
     if args.fleet > 1 or args.drain:
         return _serve_fleet(args, cfg)
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    # under jit the float32 draws fuse into their bf16 casts; eager init
+    # would hold each stacked stage's float32 leaves at once
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
     controller = None
     if args.autotune:
         from repro.autotune import ThresholdController

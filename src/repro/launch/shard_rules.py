@@ -38,10 +38,6 @@ def _spec(ndim: int, **placed) -> P:
     entries = [None] * ndim
     for pos, ax in placed.items():
         if ax is not None:
-            # canonicalize 1-tuples to the bare axis name (newer jax does
-            # this inside PartitionSpec; 0.4.37 keeps the tuple as-is)
-            if isinstance(ax, tuple) and len(ax) == 1:
-                ax = ax[0]
             entries[int(pos)] = ax
     return P(*entries)
 
@@ -248,6 +244,26 @@ def decode_loop_in_specs(params, cache, state, cfg, mesh, batch: int):
             decode_state_spec(state, cfg, mesh, batch),
             batch_spec(cfg, mesh, batch, 1),
             None)
+
+
+def place_params(params, cfg, mesh):
+    """Put the weights where :func:`decode_loop_in_specs` wants them
+    (serve1d), once: the jitted loop then takes them as they are instead
+    of resharding them on every call.  A no-op for weights already there."""
+    return jax.device_put(params, to_shardings(
+        mesh, param_spec(params, cfg, mesh, mode="serve1d")))
+
+
+def constrain_carry(cache, state, cfg, mesh, batch: int):
+    """Inside a jitted step: pin a cache and DecodeState to the layout
+    :func:`decode_loop_in_specs` gives the device loop, so a prefill's
+    outputs already sit where the next decode chunk reads them."""
+    cache = jax.lax.with_sharding_constraint(
+        cache, to_shardings(mesh, cache_spec(cache, cfg, mesh, batch)))
+    state = jax.lax.with_sharding_constraint(
+        state, to_shardings(mesh, decode_state_spec(state, cfg, mesh,
+                                                    batch)))
+    return cache, state
 
 
 def batch_spec(cfg, mesh, batch: int, ndim: int) -> P:

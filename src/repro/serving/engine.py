@@ -51,6 +51,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.exec import CONF_EMA_DECAY, StagedExecutor, effective_cohorts
 from repro.core.macs import segment_macs_per_token
+from repro.launch.shard_rules import constrain_carry, place_params
 from repro.models.model import CascadeModel, extra_input_shapes
 from repro.serving.batching import DepthCompactor, cohort_capacity
 from repro.serving.paged import PagedCascadeCache
@@ -123,6 +124,11 @@ class CascadeServingEngine:
                 "epsilon= or mac_budget=) before passing a controller")
         self.cfg = cfg
         self.model = model
+        self.mesh = mesh
+        if mesh is not None:
+            # weights go where the device loop's shardings put them, once;
+            # passed as initialised they would be resharded on every chunk
+            params = place_params(params, cfg, mesh)
         self.params = params
         # one-time layout normalization at admission capacity: lanes are
         # sized to a cohort multiple so cohort-split skipping never
@@ -280,6 +286,9 @@ class CascadeServingEngine:
     def _prefill_impl(self, params, tokens, cache, state, extra):
         d, cache, state = self.executor.prefill(params, tokens, cache, extra,
                                                 state=state)
+        if self.mesh is not None:
+            cache, state = constrain_carry(cache, state, self.cfg, self.mesh,
+                                           tokens.shape[0])
         return d.prediction, d.exit_index, d.confidence, cache, state
 
     def _decode_impl(self, params, token, cache, state, extra):
@@ -1042,7 +1051,8 @@ class CascadeServingEngine:
         remaining = np.array(
             [s.request.max_new_tokens - len(s.generated) if not s.done else 0
              for s in slots], np.int32)
-        state = lane["state"].replace(active=jnp.asarray(live))
+        # the host mask rides into the loop as is (placed by its sharding)
+        state = lane["state"].replace(active=live)
         run_before = np.asarray(state.segments_run)
         if self.paged:
             self.pcache.pool.begin_chunk()

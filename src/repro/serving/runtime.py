@@ -156,8 +156,10 @@ class DeviceDecodeLoop:
         ``(DecodeChunk, new_cache, new_state)``; the passed cache/state are
         donated and must not be reused.
         """
-        token = jnp.asarray(np.asarray(token, np.int32))
-        remaining = jnp.asarray(np.asarray(remaining, np.int32))
+        # host arrays go straight to their (possibly sharded) place at the
+        # call: a device copy made here first would sit on one device
+        token = np.asarray(token, np.int32)
+        remaining = np.asarray(remaining, np.int32)
         if self._jitted is None:
             self._jitted = self._build(params, cache, state, token.shape[0])
         t0 = time.perf_counter()

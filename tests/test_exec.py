@@ -156,8 +156,8 @@ def test_patience_serve_step_state_survives_jit_and_sharding():
 def test_decode_state_spec_structure_production_mesh():
     """decode_state_spec must cover every DecodeState leaf on the production
     mesh, batch-sharding the per-sequence leaves."""
-    from tests.test_sharding import _abstract_mesh
-    mesh = _abstract_mesh((16, 16), ("data", "model"))
+    from jax.sharding import AbstractMesh
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     cfg = get_config("qwen2.5-3b").with_cascade(confidence="patience@3")
     struct = make_decode_state_struct(cfg, 128)
     spec = decode_state_spec(struct, cfg, mesh, 128)

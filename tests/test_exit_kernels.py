@@ -212,7 +212,7 @@ def test_exit_head_megakernel_vs_oracle(B, d, V, m, n, k, decay, live_pat):
 
 def test_exit_head_megakernel_bitwise_vs_fused_kernels():
     """With MATCHING vocab tiles (the shipped defaults: both the megakernel
-    and exit_update stream vt=2048 columns) the megakernel is BIT-identical
+    and exit_update stream vt=1024 columns) the megakernel is BIT-identical
     to the unfused kernel pipeline rmsnorm_fused -> XLA matmul ->
     exit_update_fused — same streaming accumulation order, same rounding.
     This is the contract that lets cfg.kernel_tune.megakernel flip on
@@ -498,6 +498,23 @@ def test_resolve_interpret_precedence(monkeypatch):
     monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "1")
     assert resolve_interpret(None) is True       # env forces interpreter
     assert resolve_interpret(False) is False     # explicit still wins
+
+
+def test_resolve_interpret_refuses_interpreter_on_tpu(monkeypatch):
+    """On a TPU backend nothing may quietly run the Pallas interpreter:
+    auto-detection compiles, and forcing interpret mode raises."""
+    from repro.kernels import backend
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    monkeypatch.delenv("REPRO_KERNEL_INTERPRET", raising=False)
+    assert backend.resolve_interpret(None) is False
+    assert backend.resolve_interpret(False) is False
+    with pytest.raises(ValueError, match="interpret"):
+        backend.resolve_interpret(True)
+    monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "1")
+    with pytest.raises(ValueError, match="interpret"):
+        backend.resolve_interpret(None)
+    monkeypatch.setenv("REPRO_KERNEL_INTERPRET", "0")
+    assert backend.resolve_interpret(None) is False
 
 
 def test_cohort_capacity_rounds_up():

@@ -195,6 +195,39 @@ def test_decode_loop_state_survives_jit_and_mesh_sharding(tiny_model):
     assert chunk2.tokens.shape == (0, 2)
 
 
+def test_mesh_engine_places_weights_and_carry_once(tiny_model):
+    """An engine given ``mesh=`` puts the weights where the device loop's
+    rules want them at construction and pins the prefill's cache/state to
+    the loop's layout, so no chunk reshards them; its streams equal the
+    plain engine's."""
+    model, params = tiny_model
+    cfg = _tiny(thresholds=(0.6, 0.0), exit_mode="cond_batch")
+    mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1),
+                ("data", "model"))
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, cfg.vocab_size, 6).astype(np.int32)
+               for _ in range(3)]
+
+    def run(mesh):
+        eng = CascadeServingEngine(cfg, model, params, lane_batch=2,
+                                   n_lanes=2, cache_len=32,
+                                   runtime="device", chunk=4, mesh=mesh)
+        for i, p in enumerate(prompts):
+            eng.submit(Request(rid=i, prompt=p.copy(), max_new_tokens=5))
+        eng.run(100)
+        return eng
+
+    plain, sharded = run(None), run(mesh)
+    leaf = jax.tree_util.tree_leaves(sharded.params)[0]
+    assert leaf.sharding.mesh == mesh
+    k = sharded.lanes[0]["cache"]["segments"][0][0]["k"]
+    assert k.sharding.mesh == mesh
+    for rid in plain.finished:
+        assert plain.finished[rid]["tokens"] == sharded.finished[rid]["tokens"]
+        assert (plain.finished[rid]["exit_depths"]
+                == sharded.finished[rid]["exit_depths"])
+
+
 # ---------------------------------------------------------------------------
 # satellites: compile-time separation, retire decay
 # ---------------------------------------------------------------------------

@@ -13,16 +13,8 @@ from repro.configs import get_config, reduced
 from repro.launch.shard_rules import batch_spec, cache_spec, param_spec
 from repro.models.model import build_model
 
-def _abstract_mesh(shape, names):
-    """jax >= 0.4.38 takes (shape, axis_names); 0.4.37 takes shape_tuple."""
-    try:
-        return AbstractMesh(shape, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, shape)))
-
-
-MESH = _abstract_mesh((16, 16), ("data", "model"))
-MESH_MP = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH_MP = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _leaf(spec_tree, *path):

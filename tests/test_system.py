@@ -151,3 +151,24 @@ def test_trainability_mask_llm_layout():
     m1 = trainability_mask(params, plan[1])       # head 0 only
     assert bool(m1["exits"][0]["norm"]["w"])
     assert not bool(m1["embed"]) and not bool(m1["lm_head"])
+
+
+def test_compile_cache_dir_env_wins_else_repo_path(monkeypatch):
+    """The persistent compilation cache lives where
+    JAX_COMPILATION_CACHE_DIR says (the helper sets nothing then), and
+    otherwise at the fixed <repo>/.jax_cache."""
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(compile_cache.ENV, "/elsewhere/cache")
+        assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv(compile_cache.ENV)
+        got = compile_cache.enable_compile_cache()
+        repo = Path(__file__).resolve().parents[1]
+        assert got == str(repo / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

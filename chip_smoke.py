@@ -28,8 +28,9 @@ One chip:
 
 Four chips (``--chips 4``): the device runtime with ``mesh=`` over the four
 chips, lanes data-parallel by ``repro.launch.shard_rules``, beside the same
-requests on a one-device engine; tokens must be identical at 0.0 and 1.1,
-and decode chunks must run without a device-to-device copy.
+requests on a one-device engine that decodes as many rows per dispatch as
+each chip does; tokens must be identical at 0.0 and 1.1, and decode chunks
+must run without a device-to-device copy.
 
 The last line of standard output is
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``.
@@ -148,7 +149,8 @@ def with_thresholds(cfg, th: float):
 
 
 def serve(cfg, params, prompts, runtime: str = "device", mesh=None,
-          guard_chunks: bool = False) -> dict:
+          guard_chunks: bool = False, lane_batch: int = LANE_BATCH,
+          n_lanes: int = LANES) -> dict:
     """Serve every prompt through a fresh engine; check the outputs are
     well-formed and return them as (N, MAX_NEW) arrays plus stats.
 
@@ -159,7 +161,7 @@ def serve(cfg, params, prompts, runtime: str = "device", mesh=None,
     from repro.models.model import build_model
     from repro.serving import CascadeServingEngine, Request
     eng = CascadeServingEngine(cfg, build_model(cfg), params,
-                               lane_batch=LANE_BATCH, n_lanes=LANES,
+                               lane_batch=lane_batch, n_lanes=n_lanes,
                                cache_len=CACHE_LEN, runtime=runtime,
                                chunk=CHUNK, mesh=mesh)
     for i, p in enumerate(prompts):
@@ -200,13 +202,15 @@ def serve(cfg, params, prompts, runtime: str = "device", mesh=None,
            "us_per_token": st["wallclock_us_per_token"],
            "wall_seconds": wall, "guarded_ticks": guarded}
     if mesh is not None:
-        lane = eng.lanes[0]
-        k0 = lane["cache"]["segments"][0][0]["k"]
+        ks = [ln["cache"]["segments"][0][0]["k"] for ln in eng.lanes]
+        k0 = ks[0]
         w0 = jax.tree_util.tree_leaves(eng.params)[0]
         out["cache_leaf_sharding"] = str(k0.sharding.spec)
         out["cache_leaf_shard_shapes"] = sorted(
             {str(s.data.shape) for s in k0.addressable_shards})
-        out["cache_leaf_devices"] = len(k0.sharding.device_set)
+        # fewest devices any lane's cache spans (an idle lane's too)
+        out["cache_leaf_devices"] = min(len(k.sharding.device_set)
+                                        for k in ks)
         out["param_leaf_devices"] = len(w0.sharding.device_set)
         out["bytes_in_use"] = device_bytes()
     del eng
@@ -349,21 +353,37 @@ def phase_mesh(cfg, seed: int, prompts, n_chips: int) -> None:
     # the one-device engine reads device 0's replica: no second copy
     one = jax.tree_util.tree_map(lambda x: x.addressable_data(0), params)
     report("mesh", mesh=dict(mesh.shape), bytes_in_use=device_bytes())
+    # The one-device engine decodes as many rows per dispatch as each chip
+    # of the mesh does (lane_batch / n_chips rows, in n_chips times the
+    # lanes).  XLA's TPU code for a 2-row and an 8-row matmul need not
+    # round alike, and on random weights (top softmax ~1e-3 over 151,936
+    # tokens) one rounding difference changes a greedy token.  The 8-row
+    # engine is reported beside it.
+    per_chip = LANE_BATCH // n_chips
     for th in (NEVER, ALWAYS):
         c = with_thresholds(cfg, th)
-        single = serve(c, one, prompts)
         sharded = serve(c, params, prompts, mesh=mesh, guard_chunks=True)
+        single = serve(c, one, prompts, lane_batch=per_chip,
+                       n_lanes=LANES * n_chips)
+        wide = serve(c, one, prompts)
         same = bool(np.array_equal(single["tokens"], sharded["tokens"]))
-        report("mesh", threshold=th, tokens_identical=same,
+        report("mesh", threshold=th, one_device_lane_batch=per_chip,
+               tokens_identical=same,
                exits_identical=bool(np.array_equal(single["exits"],
                                                    sharded["exits"])),
+               token_agreement_with_lane_batch_8=float(
+                   (wide["tokens"] == sharded["tokens"]).mean()),
+               first_token_confs_identical_to_lane_batch_8=bool(
+                   np.array_equal(wide["confs"][:, 0],
+                                  sharded["confs"][:, 0])),
                decode_ticks_without_device_copies=sharded["guarded_ticks"],
                cache_leaf_sharding=sharded["cache_leaf_sharding"],
                cache_leaf_shard_shapes=sharded["cache_leaf_shard_shapes"],
                cache_leaf_devices=sharded["cache_leaf_devices"],
                param_leaf_devices=sharded["param_leaf_devices"],
                bytes_in_use_with_mesh_engine=sharded["bytes_in_use"],
-               single=summary(single), sharded=summary(sharded))
+               single=summary(single), sharded=summary(sharded),
+               single_lane_batch_8=summary(wide))
         check(same, f"threshold {th}: mesh tokens differ from one device")
         check(sharded["guarded_ticks"] > 0, "no decode-only tick guarded")
         check(sharded["cache_leaf_devices"] == n_chips

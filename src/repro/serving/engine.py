@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import copy
 import dataclasses
+import functools
 import itertools
 import time
 from typing import Dict, List, Optional
@@ -51,7 +52,8 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.exec import CONF_EMA_DECAY, StagedExecutor, effective_cohorts
 from repro.core.macs import segment_macs_per_token
-from repro.launch.shard_rules import constrain_carry, place_params
+from repro.launch.shard_rules import (cache_spec, constrain_carry,
+                                     place_params, to_shardings)
 from repro.models.model import CascadeModel, extra_input_shapes
 from repro.serving.batching import DepthCompactor, cohort_capacity
 from repro.serving.paged import PagedCascadeCache
@@ -180,6 +182,14 @@ class CascadeServingEngine:
         self._dense_cache_bytes = n_lanes * int(sum(
             int(np.prod(l.shape)) * l.dtype.itemsize
             for l in jax.tree_util.tree_leaves(tmpl["segments"])))
+        # a fresh dense lane cache, made where the device loop reads it:
+        # under a mesh, made whole on device 0, every idle lane would cost
+        # that device a full lane cache
+        self._init_cache = functools.partial(model.init_cache, lane_batch,
+                                             cache_len)
+        if mesh is not None:
+            self._init_cache = jax.jit(self._init_cache, out_shardings=(
+                to_shardings(mesh, cache_spec(tmpl, cfg, mesh, lane_batch))))
         self.lanes = []
         for i in range(n_lanes):
             lane = {
@@ -193,7 +203,7 @@ class CascadeServingEngine:
                 lane["cache"] = None
                 lane["kpos"] = self.pcache.fresh_kpos()
             else:
-                lane["cache"] = model.init_cache(lane_batch, cache_len)
+                lane["cache"] = self._init_cache()
             self.lanes.append(lane)
         self.queue: List[Request] = []
         self.finished: Dict[int, dict] = {}
@@ -789,7 +799,7 @@ class CascadeServingEngine:
             cache_in = self.pcache.lane_cache(lane["kpos"])
             self._tables_stale.discard(lane_id)
         else:
-            cache_in = self.model.init_cache(self.lane_batch, self.cache_len)
+            cache_in = self._init_cache()
         extra = self._extra(self.lane_batch)
         # re-prefill restarts the lane's DecodeState (streaks, EMA, cursors);
         # the prefill decision itself counts as the streak's first step.

@@ -197,9 +197,9 @@ def test_decode_loop_state_survives_jit_and_mesh_sharding(tiny_model):
 
 def test_mesh_engine_places_weights_and_carry_once(tiny_model):
     """An engine given ``mesh=`` puts the weights where the device loop's
-    rules want them at construction and pins the prefill's cache/state to
-    the loop's layout, so no chunk reshards them; its streams equal the
-    plain engine's."""
+    rules want them at construction, makes every lane's cache there too,
+    and pins the prefill's cache/state to the loop's layout, so no chunk
+    reshards them; its streams equal the plain engine's."""
     model, params = tiny_model
     cfg = _tiny(thresholds=(0.6, 0.0), exit_mode="cond_batch")
     mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1),
@@ -212,6 +212,10 @@ def test_mesh_engine_places_weights_and_carry_once(tiny_model):
         eng = CascadeServingEngine(cfg, model, params, lane_batch=2,
                                    n_lanes=2, cache_len=32,
                                    runtime="device", chunk=4, mesh=mesh)
+        if mesh is not None:
+            for lane in eng.lanes:
+                for leaf in jax.tree_util.tree_leaves(lane["cache"]):
+                    assert leaf.sharding.mesh == mesh
         for i, p in enumerate(prompts):
             eng.submit(Request(rid=i, prompt=p.copy(), max_new_tokens=5))
         eng.run(100)

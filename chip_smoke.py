@@ -7,7 +7,7 @@
 One process, no child processes.  Each phase prints what it found on lines
 of its own (``<phase>: {json}``); a failed check raises, so the script
 exits non-zero and never prints the last line.  Weights are random, drawn
-from ``--seed``, at the published widths (36 layers, d 2048, 16/2 heads,
+from ``SEED``, at the published widths (36 layers, d 2048, 16/2 heads,
 ff 11008, vocab 151,936, bf16) with the default 3-component cascade.
 
 One chip:
@@ -24,7 +24,10 @@ One chip:
 * serve_kernels — the same requests with every Pallas kernel on (the
   exit-head megakernel and cohort scatter too; two cohorts at the mixed
   point), which must run Mosaic-compiled: exit indices at 0.0 and 1.1 equal
-  the XLA run's, prefill logits of every component agree with the XLA path.
+  the XLA run's; at every threshold each decode step of the kernel run,
+  replayed from XLA's cache, agrees with XLA's step (confidence and token
+  against XLA's logits, exit index, the cache it writes); prefill logits of
+  every component agree with the XLA path.
 
 Four chips (``--chips 4``): the device runtime with ``mesh=`` over the four
 chips, lanes data-parallel by ``repro.launch.shard_rules``, beside the same
@@ -50,6 +53,7 @@ import numpy as np
 ROOT = os.path.dirname(os.path.abspath(__file__))
 
 ARCH = "qwen2.5-3b"
+SEED = 0
 N_REQUESTS, PROMPT_LEN, MAX_NEW = 8, 64, 16
 LANES, LANE_BATCH, CACHE_LEN, CHUNK = 2, 8, 1024, 8
 NEVER, ALWAYS = 1.1, 0.0
@@ -65,6 +69,12 @@ COMPARE_LEN = 128
 # estimate, and is still 16x tighter than unrelated logits (relative
 # error ~sqrt(2)).
 LOGIT_REL_TOL = 2.0 ** -4
+# One logit's kernel-vs-XLA error: LOGIT_REL_TOL bounds the error's rms
+# relative to the logits' rms, and a single logit's error stays within 4
+# such rms (a Gaussian draw beyond 4 sigma has odds 6e-5).  A
+# log-confidence (max logit - logsumexp) moves by about one logit's
+# error, the gap between two logits by up to two.
+LOGIT_SIGMAS = 4
 
 
 def report(phase: str, **fields) -> None:
@@ -118,14 +128,14 @@ def device_bytes(stat: str = "bytes_in_use"):
     return out
 
 
-def phase_build(cfg, seed: int, out_shardings=None):
+def phase_build(cfg, out_shardings=None):
     import jax
     from repro.models.model import build_model
     from repro.utils import tree_bytes, tree_size
     model = build_model(cfg)
     t0 = time.perf_counter()
     params = jax.jit(model.init, out_shardings=out_shardings)(
-        jax.random.PRNGKey(seed))
+        jax.random.PRNGKey(SEED))
     jax.block_until_ready(params)
     report("build", arch=cfg.name, layers=cfg.n_layers, d_model=cfg.d_model,
            heads=[cfg.n_heads, cfg.n_kv_heads], d_ff=cfg.d_ff,
@@ -137,8 +147,8 @@ def phase_build(cfg, seed: int, out_shardings=None):
     return model, params
 
 
-def make_prompts(cfg, seed: int) -> np.ndarray:
-    rng = np.random.default_rng(seed)
+def make_prompts(cfg) -> np.ndarray:
+    rng = np.random.default_rng(SEED)
     return rng.integers(0, cfg.vocab_size,
                         (N_REQUESTS, PROMPT_LEN)).astype(np.int32)
 
@@ -282,9 +292,132 @@ def phase_serve_xla(cfg, model, params, prompts) -> dict:
     return runs
 
 
+def replay_decode(xcfg, kcfg, params, prompts, tokens, dense_logits) -> dict:
+    """Hold the kernel decode path to XLA one step at a time.
+
+    XLA prefills the prompts; then at every step the kernel decode and the
+    XLA decode (``xcfg``: the same thresholds and cohorts, no kernels) take
+    the same token, the kernel run's own, from one shared cache and state,
+    XLA's, so no error carries from one step to the next.  Per row, the
+    kernel's confidence at its exit component and the logit of the token it
+    chose are held to XLA's dense logits there (``LOGIT_SIGMAS`` per-logit
+    error bounds); its exit index equals XLA's unless the confidence that
+    decided it sits within that bound of the threshold; and the cache it
+    wrote equals XLA's, the written slot within ``LOGIT_REL_TOL`` and every
+    other slot bit for bit.  With two cohorts, steps where one cohort ran a
+    segment and the other skipped it are the ones that take the cohort
+    scatter; they must occur."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.exec import StagedExecutor, effective_cohorts
+    from repro.models.model import build_model
+    xm = build_model(xcfg)
+    xe, ke = StagedExecutor(xm), StagedExecutor(build_model(kcfg))
+    B = len(prompts)
+    n_c = effective_cohorts(kcfg.cascade.n_cohorts, B)
+    log_th = np.log(np.maximum(np.asarray(xcfg.cascade.thresholds), 1e-30))
+
+    @jax.jit
+    def prefill(p, toks):
+        _, cache, state = xe.prefill(p, toks, xm.init_cache(B, CACHE_LEN))
+        return cache, state
+
+    @jax.jit
+    def cache_error(kc, xc, t):
+        """(largest |difference| off the slot written at position t,
+        kpos included; per segment, that slot's relative L2 difference)."""
+        slot = t % kc["kpos"].shape[-1]
+        off = jnp.max(jnp.abs(kc["kpos"] - xc["kpos"])).astype(jnp.float32)
+        rel = []
+        for ks, xs in zip(kc["segments"], xc["segments"]):
+            num = den = jnp.zeros((), jnp.float32)
+            for a, b in zip(jax.tree_util.tree_leaves(ks),
+                            jax.tree_util.tree_leaves(xs)):
+                # stacked (layers, B, W, ...) cache leaves
+                at = (jnp.arange(a.shape[2]) == slot).reshape(
+                    (1, 1, -1) + (1,) * (a.ndim - 3))
+                d = a.astype(jnp.float32) - b.astype(jnp.float32)
+                off = jnp.maximum(off, jnp.max(jnp.where(at, 0.0,
+                                                         jnp.abs(d))))
+                num = num + jnp.sum(jnp.where(at, d * d, 0.0))
+                den = den + jnp.sum(jnp.where(
+                    at, jnp.square(b.astype(jnp.float32)), 0.0))
+            rel.append(jnp.sqrt(num / den))
+        return off, jnp.stack(rel)
+
+    def ref(x):
+        """(log-confidence, per-logit error bound) of one row of logits."""
+        top = x.max()
+        lc = top - (top + np.log(np.exp(x - top).sum()))
+        return lc, LOGIT_SIGMAS * LOGIT_REL_TOL * np.sqrt(np.mean(x * x))
+
+    kernel_step, xla_step = jax.jit(ke.decode_step), jax.jit(xe.decode_step)
+    cache, state = prefill(params, jnp.asarray(prompts))
+    conf_err, token_gap, argmax_same, ambiguous = [], [], 0, 0
+    slot_rel, off_slot, splits, caches_compared = [], 0.0, 0, 0
+    for j in range(tokens.shape[1] - 1):
+        tok = jnp.asarray(tokens[:, j:j + 1])
+        logits = [np.asarray(x, np.float64)
+                  for x in dense_logits(params, tok, cache, state.t)]
+        dk, ck, sk = kernel_step(params, tok, cache, state)
+        dx, cx, sx = xla_step(params, tok, cache, state)
+        ek, pk, confk = (np.asarray(v) for v in (
+            dk.exit_index, dk.prediction, dk.confidence))
+        ex = np.asarray(dx.exit_index)
+        for r in range(B):
+            x = logits[ek[r]][r]
+            lc, bound = ref(x)
+            conf_err.append(abs(np.log(confk[r]) - lc) / bound)
+            token_gap.append((x.max() - x[pk[r]]) / (2 * bound))
+            argmax_same += int(pk[r] == x.argmax())
+            if ek[r] != ex[r]:
+                m = min(ek[r], ex[r])
+                lc_m, bound_m = ref(logits[m][r])
+                check(abs(lc_m - log_th[m]) <= bound_m,
+                      f"step {j} row {r}: kernel exit {ek[r]}, XLA exit "
+                      f"{ex[r]}, confidence not at the threshold")
+                ambiguous += 1
+        ran = np.asarray(sk.segments_run) - np.asarray(state.segments_run)
+        splits += int(((ran[1:] > 0) & (ran[1:] < n_c)).any())
+        if np.array_equal(ek, ex):
+            # same exits, same skips: the caches must agree
+            off, rel = cache_error(ck, cx, state.t)
+            off_slot = max(off_slot, float(off))
+            slot_rel.append(np.asarray(rel))
+            caches_compared += 1
+        cache, state = cx, sx
+    out = {"steps": tokens.shape[1] - 1,
+           "worst_conf_error_over_bound": max(conf_err),
+           "worst_token_logit_gap_over_bound": max(token_gap),
+           "argmax_agreement": argmax_same / len(conf_err),
+           "exits_differing_at_threshold": ambiguous,
+           "caches_compared": caches_compared,
+           "worst_written_slot_rel_l2": (np.max(slot_rel, axis=0)
+                                         if slot_rel else None),
+           "largest_off_slot_difference": off_slot,
+           "cohort_split_steps": splits}
+    return out
+
+
+def check_replay(th, n_cohorts: int, rep: dict) -> None:
+    check(rep["worst_conf_error_over_bound"] <= 1.0,
+          f"threshold {th}: kernel confidence off XLA's beyond the bound")
+    check(rep["worst_token_logit_gap_over_bound"] <= 1.0,
+          f"threshold {th}: kernel token not XLA's argmax within the bound")
+    check(rep["caches_compared"] > 0, f"threshold {th}: no cache compared")
+    check(rep["largest_off_slot_difference"] == 0.0,
+          f"threshold {th}: kernel decode changed cache slots it must not")
+    check((rep["worst_written_slot_rel_l2"] <= LOGIT_REL_TOL).all(),
+          f"threshold {th}: kernel-written cache slot off XLA's")
+    if n_cohorts > 1:
+        check(rep["cohort_split_steps"] > 0,
+              f"threshold {th}: no step split the cohorts")
+
+
 def phase_serve_kernels(cfg, params, prompts, xla_runs: dict,
                         expect_backend: str = "compiled") -> dict:
     import jax
+    import jax.numpy as jnp
     from repro.models.model import build_model
     from repro.serving.runtime import kernel_provenance
     kcfg = cfg.replace(use_kernels=True).with_kernel_tune(
@@ -293,21 +426,29 @@ def phase_serve_kernels(cfg, params, prompts, xla_runs: dict,
     report("serve_kernels", **prov)
     check(prov["kernel_backend"] == expect_backend,
           f"kernels run {prov['kernel_backend']}, not {expect_backend}")
+    xm = build_model(cfg)
+    # every component's logits of one dense XLA decode step
+    dense_logits = jax.jit(lambda p, tok, c, t: [
+        x.astype(jnp.float32) for x in xm.decode_step(p, tok, t, c)[0]])
     mid = [t for t in xla_runs if t not in (NEVER, ALWAYS)][0]
     runs = {}
     for th in (NEVER, ALWAYS, mid):
         c = with_thresholds(kcfg, th)
         if th == mid:
             c = c.with_cascade(n_cohorts=2)
+        n_c = c.cascade.n_cohorts
         run = runs[th] = serve(c, params, prompts)
         ref = xla_runs[th]
         agree = float((run["tokens"] == ref["tokens"]).mean())
-        report("serve_kernels", threshold=th,
-               n_cohorts=c.cascade.n_cohorts,
+        report("serve_kernels", threshold=th, n_cohorts=n_c,
                token_agreement_with_xla=agree, **summary(run))
         if th in (NEVER, ALWAYS):
             check(np.array_equal(run["exits"], ref["exits"]),
                   f"threshold {th}: kernel exit indices differ from XLA")
+        rep = replay_decode(with_thresholds(cfg, th).with_cascade(
+            n_cohorts=n_c), c, params, prompts, run["tokens"], dense_logits)
+        report("serve_kernels", threshold=th, decode_replay=rep)
+        check_replay(th, n_c, rep)
 
     rng = np.random.default_rng(1)
     toks = rng.integers(0, cfg.vocab_size,
@@ -334,7 +475,7 @@ def phase_serve_kernels(cfg, params, prompts, xla_runs: dict,
     return runs
 
 
-def phase_mesh(cfg, seed: int, prompts, n_chips: int) -> None:
+def phase_mesh(cfg, prompts, n_chips: int) -> None:
     """The device runtime on a data-parallel mesh over ``n_chips`` beside
     a one-device engine, both over one set of weights placed by the
     sharding rules (replicated: serve1d with a model axis of 1)."""
@@ -346,10 +487,10 @@ def phase_mesh(cfg, seed: int, prompts, n_chips: int) -> None:
         np.asarray(jax.devices()[:n_chips]).reshape(n_chips, 1),
         ("data", "model"))
     model = build_model(cfg)
-    abstract = jax.eval_shape(model.init, jax.random.PRNGKey(seed))
+    abstract = jax.eval_shape(model.init, jax.random.PRNGKey(SEED))
     shardings = to_shardings(mesh, param_spec(abstract, cfg, mesh,
                                               mode="serve1d"))
-    _, params = phase_build(cfg, seed, out_shardings=shardings)
+    _, params = phase_build(cfg, out_shardings=shardings)
     # the one-device engine reads device 0's replica: no second copy
     one = jax.tree_util.tree_map(lambda x: x.addressable_data(0), params)
     report("mesh", mesh=dict(mesh.shape), bytes_in_use=device_bytes())
@@ -397,7 +538,6 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
                     help="4 runs only the four-chip mesh path")
-    ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
     t_start = time.perf_counter()
     device = phase_device(args.chips)
@@ -407,12 +547,12 @@ def main(argv=None) -> int:
     report("compile_cache", dir=enable_compile_cache())
 
     cfg = smoke_config()
-    prompts = make_prompts(cfg, args.seed)
+    prompts = make_prompts(cfg)
     if args.chips > 1:
-        phase_mesh(cfg, args.seed, prompts, args.chips)
+        phase_mesh(cfg, prompts, args.chips)
         report("done", total_seconds=time.perf_counter() - t_start)
     else:
-        model, params = phase_build(cfg, args.seed)
+        model, params = phase_build(cfg)
         xla = phase_serve_xla(cfg, model, params, prompts)
         kern = phase_serve_kernels(cfg, params, prompts, xla)
         # each engine's first decode dispatch: compile (or cache load) + run

@@ -5,11 +5,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding
 
 from repro.configs import get_config, reduced
 from repro.core.exec import DecodeState, StagedExecutor, effective_cohorts
 from repro.core.policy import ConfidenceMeasure, register_measure
+from repro.launch.shard_rules import cache_spec, to_shardings
 from repro.models.model import build_model
 from repro.serving import (CascadeServingEngine, DepthCompactor,
                            DeviceDecodeLoop, Request)
@@ -208,17 +209,35 @@ def test_mesh_engine_places_weights_and_carry_once(tiny_model):
     prompts = [rng.integers(0, cfg.vocab_size, 6).astype(np.int32)
                for _ in range(3)]
 
+    def assert_caches_placed(eng, exact):
+        # leaf by leaf, the rules' NamedSharding: a cache made without
+        # out_shardings (a SingleDeviceSharding) fails even on one device.
+        # A loop output's spec drops the size-1 axis, hence ``exact`` only
+        # for caches as the engine made them.
+        want = to_shardings(mesh, cache_spec(eng.lanes[0]["cache"], cfg,
+                                             mesh, 2))
+        for lane in eng.lanes:
+            got = jax.tree_util.tree_map(lambda x: x.sharding,
+                                         lane["cache"])
+            assert jax.tree_util.tree_structure(got) == \
+                jax.tree_util.tree_structure(want)
+            for g, w, x in zip(jax.tree_util.tree_leaves(got),
+                               jax.tree_util.tree_leaves(want),
+                               jax.tree_util.tree_leaves(lane["cache"])):
+                assert isinstance(g, NamedSharding) and g.mesh == mesh
+                assert (g == w) if exact else g.is_equivalent_to(w, x.ndim)
+
     def run(mesh):
         eng = CascadeServingEngine(cfg, model, params, lane_batch=2,
                                    n_lanes=2, cache_len=32,
                                    runtime="device", chunk=4, mesh=mesh)
         if mesh is not None:
-            for lane in eng.lanes:
-                for leaf in jax.tree_util.tree_leaves(lane["cache"]):
-                    assert leaf.sharding.mesh == mesh
+            assert_caches_placed(eng, exact=True)
         for i, p in enumerate(prompts):
             eng.submit(Request(rid=i, prompt=p.copy(), max_new_tokens=5))
         eng.run(100)
+        if mesh is not None:
+            assert_caches_placed(eng, exact=False)
         return eng
 
     plain, sharded = run(None), run(mesh)

@@ -323,12 +323,12 @@ class ObsConfig:
     depth) → prefill → per-chunk decode (tokens, exit components,
     confidence at exit) → exit | escalate | migrate → finalize — entirely
     host-side, from data the jitted programs already return at existing
-    host-sync boundaries plus ``perf_counter`` stamps around them.  The
-    device programs gain ZERO new host syncs and ZERO retraces: recording
-    never touches a traced graph, so token/exit/confidence streams are
-    bit-identical recorder-on vs recorder-off (pinned by
-    ``tests/test_obs.py`` and gated ≥ 0.97 throughput ratio in
-    ``BENCH_serving.json["obs"]``).
+    host-sync boundaries plus the ``perf_counter`` edges of the engine's
+    program spans (:mod:`repro.obs.spans`, which are on whether or not
+    the recorder is).  Recording adds no host read and no retrace, so
+    token/exit/confidence streams are bit-identical recorder-on vs
+    recorder-off (pinned by ``tests/test_obs.py``).  Its cost on the chip
+    is not measured apart; the spans' is recorded in PERF.md.
 
     ``max_flights`` bounds the ring buffer of COMPLETED flight records
     (live flights are bounded by slot capacity); the oldest record is

@@ -51,6 +51,14 @@ The per-slot ``DecodeState.active`` mask also rides in the decode context
 (``ctx["live"]``), where the exit-masked decode-attention kernel early-outs
 dead slots' grid cells (``cfg.use_kernels``).
 
+Every segment computation runs under ``jax.named_scope("segment{k}")``,
+every exit-head evaluation (norm, unembedding, confidence and exit
+update, or the megakernel call) under ``"exit_head{k}"`` and every K/V
+backfill of a skipped segment under ``"backfill{k}"``, in each branch
+that computes them (prefill's live in ``models/model.py``).  The scopes
+set HLO ``op_name`` metadata only, so a profile attributes device time
+to them without changing the program.
+
 This replaces the old fixed ``(params, token, t, cache, extra)`` serve-step
 signature: launch steps and the serving engine now thread
 ``(params, token, cache, state, extra)`` with ``state: DecodeState`` (see
@@ -314,16 +322,31 @@ class StagedExecutor:
         :meth:`ExitDecider.scan_logits`, unchanged semantics.
         """
         decider, model = self.decider, self.model
-        if self.use_megakernel:
-            hp = model.exit_head_params(params, si)
-            if hp is not None:
-                return decider.scan_hidden(
-                    si, self.n_components, h[:, 0, :], hp[0], hp[1], ths,
-                    carry=sc, state=state, live=live,
-                    eps=self.cfg.norm_eps)
-        lg = model.exit_logits(params, si, h)[:, 0, :]
-        return decider.scan_logits(si, self.n_components, lg, ths, sc,
-                                   state=state)
+        with jax.named_scope(f"exit_head{si}"):
+            if self.use_megakernel:
+                hp = model.exit_head_params(params, si)
+                if hp is not None:
+                    return decider.scan_hidden(
+                        si, self.n_components, h[:, 0, :], hp[0], hp[1],
+                        ths, carry=sc, state=state, live=live,
+                        eps=self.cfg.norm_eps)
+            lg = model.exit_logits(params, si, h)[:, 0, :]
+            return decider.scan_logits(si, self.n_components, lg, ths, sc,
+                                       state=state)
+
+    def _run_segment(self, si, params, h, ctx, seg_cache):
+        """Compute segment ``si`` (under its ``segment{si}`` scope)."""
+        with jax.named_scope(f"segment{si}"):
+            return self.model.run_segment(si, params, h, ctx, seg_cache)
+
+    def _backfill(self, si, params, h, ctx, seg_cache):
+        """Segment ``si``'s K/V backfill from the exit hidden state (under
+        its ``backfill{si}`` scope); the caches as they are without
+        ``cascade.state_backfill``."""
+        if not self.cfg.cascade.state_backfill:
+            return seg_cache
+        with jax.named_scope(f"backfill{si}"):
+            return self.model.backfill_segment(si, params, h, ctx, seg_cache)
 
     # ------------------------------------------------------------------
     def _segment_paths(self, si, ctx_c, params, ths):
@@ -345,18 +368,13 @@ class StagedExecutor:
         :func:`repro.kernels.exit_update.exit_update` — for fixed-graph
         callers without a cross-branch bit-identity contract.)
         """
-        model, decider, n_m = self.model, self.decider, self.n_components
-
         def run(h, seg_cache, sc):
-            h2, nc2, _ = model.run_segment(si, params, h, ctx_c, seg_cache)
+            h2, nc2, _ = self._run_segment(si, params, h, ctx_c, seg_cache)
             return h2, nc2, self._scan_exit(si, params, h2, ths, sc,
                                             live=ctx_c.get("live"))
 
         def skip(h, seg_cache, sc):
-            if self.cfg.cascade.state_backfill:
-                seg_cache = model.backfill_segment(si, params, h, ctx_c,
-                                                   seg_cache)
-            return h, seg_cache, sc
+            return h, self._backfill(si, params, h, ctx_c, seg_cache), sc
 
         return run, skip
 
@@ -392,8 +410,6 @@ class StagedExecutor:
             h, nc, sc = jax.tree_util.tree_map(
                 lambda a, b: jnp.where(skip, a, b), lite, full)
             return h, nc, sc, jnp.asarray(1, jnp.int32), hs
-        model, decider, n_m = self.model, self.decider, self.n_components
-
         def run4(h, seg_cache, sc, hs):
             h2, nc2, sc2 = run(h, seg_cache, sc)
             return h2, nc2, sc2, h2          # shadow chain = real chain
@@ -401,7 +417,7 @@ class StagedExecutor:
         def observe4(h, seg_cache, sc, hs):
             # full-depth OBSERVATION: compute from the shadow chain, keep
             # only the telemetry rider row; commit the skip results
-            h2s, _, _ = model.run_segment(si, params, hs, ctx_c, seg_cache)
+            h2s, _, _ = self._run_segment(si, params, hs, ctx_c, seg_cache)
             sc_obs = self._scan_exit(si, params, h2s, ths, sc,
                                      live=ctx_c.get("live"))
             sc = {**sc, "tcode": sc_obs["tcode"]}
@@ -503,7 +519,7 @@ class StagedExecutor:
         ran = [jnp.asarray(C, jnp.int32)]
 
         # segment 0 computes for everyone (every cohort needs it)
-        h, nc, _ = model.run_segment(0, params, h, ctx, segs[0])
+        h, nc, _ = self._run_segment(0, params, h, ctx, segs[0])
         new_segs.append(nc)
         sc = self._scan_exit(0, params, h, ths, state=state.policy,
                              live=state.active)
@@ -590,10 +606,8 @@ class StagedExecutor:
                                    for s, a in zip(sc_parts, act_parts)])
 
                 def _all_skip(hp, seg, scp, hsp, _si=si):
-                    if self.cfg.cascade.state_backfill:
-                        seg = model.backfill_segment(
-                            _si, params, jnp.concatenate(hp, axis=0), ctx,
-                            seg)
+                    seg = self._backfill(
+                        _si, params, jnp.concatenate(hp, axis=0), ctx, seg)
                     return (list(hp), seg, list(scp),
                             jnp.zeros((), jnp.int32), hsp)
 
@@ -647,7 +661,7 @@ class StagedExecutor:
                     return hp, nc, scp, r, hsp
 
                 def _all_run(hp, seg, scp, hsp, _si=si):
-                    h2, nc, _ = model.run_segment(
+                    h2, nc, _ = self._run_segment(
                         _si, params, jnp.concatenate(hp, axis=0), ctx, seg)
                     sc2 = self._scan_exit(_si, params, h2, ths,
                                           decider.concat_carry(list(scp)),

@@ -362,13 +362,7 @@ class CascadeModel:
                "shared": params.get("shared"), "kpos": cache["kpos"]}
         if block_tables is not None:
             ctx["block_tables"] = jnp.asarray(block_tables, jnp.int32)
-        logits = []
-        new_segs = []
-        for si in range(self.n_exits):
-            h, nc, _ = self._run_segment(si, params, h, ctx,
-                                         cache["segments"][si])
-            new_segs.append(nc)
-            logits.append(self.exit_logits(params, si, h[:, -1:, :])[:, 0, :])
+        logits, new_segs = self._prefill_segments(params, h, ctx, cache)
         kpos = jnp.asarray(_prefill_kpos(S, W))
         if cache["kpos"].ndim == 2:
             kpos = jnp.broadcast_to(kpos, (B, W))
@@ -395,12 +389,22 @@ class CascadeModel:
                "cross": self._make_cross(params, extra or {}, "full"),
                "shared": params.get("shared"), "kpos": None,
                "block_tables": jnp.asarray(block_tables, jnp.int32)}
+        return self._prefill_segments(params, h, ctx, cache)
+
+    def _prefill_segments(self, params, h, ctx, cache):
+        """Every segment over the prompt, each exit head at its last
+        position, under the staged executor's ``segment{k}`` /
+        ``exit_head{k}`` scopes.  Returns (exit logits, new segment
+        caches)."""
         logits, new_segs = [], []
         for si in range(self.n_exits):
-            h, nc, _ = self._run_segment(si, params, h, ctx,
-                                         cache["segments"][si])
+            with jax.named_scope(f"segment{si}"):
+                h, nc, _ = self._run_segment(si, params, h, ctx,
+                                             cache["segments"][si])
             new_segs.append(nc)
-            logits.append(self.exit_logits(params, si, h[:, -1:, :])[:, 0, :])
+            with jax.named_scope(f"exit_head{si}"):
+                logits.append(
+                    self.exit_logits(params, si, h[:, -1:, :])[:, 0, :])
         return logits, new_segs
 
     # ------------------------------------------------------------------

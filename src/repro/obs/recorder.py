@@ -3,11 +3,11 @@
 The serving engine already syncs to the host at well-defined points —
 admission, lane (re)prefill, once per decode chunk, retire — and at each
 of those points the data a trace needs (tokens, exit components,
-confidences, segment-execution deltas) is ALREADY on the host as numpy.
-The recorder simply stamps ``time.perf_counter`` around those existing
-boundaries and files the data into per-request flights, so the jitted
-programs gain **zero new host syncs and zero retraces**; token streams
-are bit-identical recorder-on vs recorder-off.
+confidences, segment-execution deltas) is already on the host as numpy.
+The recorder files that data into per-request flights, timed by the
+``perf_counter`` edges of the engine's program spans
+(:mod:`repro.obs.spans`), so it adds no host read and no retrace; token
+streams are bit-identical recorder-on vs recorder-off.
 
 Structures:
 
@@ -170,7 +170,6 @@ class FlightRecorder:
         self.evicted = 0
         self.events = EventLog(max_events, clock=clock)
         self.reservoirs = {
-            "admission_wait_ticks": _Reservoir(reservoir),
             "e2e_seconds": _Reservoir(reservoir),
             "per_token_seconds": _Reservoir(reservoir),
             "macs_per_request": _Reservoir(reservoir),
@@ -210,13 +209,16 @@ class FlightRecorder:
             a.update(attrs)
         f.span("admit", t, t, a)
         f.attrs.update({k: v for k, v in a.items() if k != "tick"})
-        self.reservoirs["admission_wait_ticks"].add(wait_ticks)
 
     def on_prefill(self, lane: int, t0: float, seconds: float,
-                   rids: List[int], fresh: List[int], positions: int):
+                   rids: List[int], fresh: List[int], positions: int,
+                   prep_s: float, launch_s: float, sync_s: float):
         """A lane (re)prefill dispatch: one span on every FRESH rid it
         admitted (in-flight co-residents re-prefill as a side effect and
-        get a ``reprefill`` span instead), plus a lane-track slice."""
+        get a ``reprefill`` span instead), plus a lane-track slice.
+        ``seconds`` runs from the jitted call to its first token on the
+        host; ``prep_s`` / ``launch_s`` / ``sync_s`` are the engine's
+        ``engine.prefill.*`` spans."""
         fresh_set = set(fresh)
         for rid in rids:
             f = self.live.get(rid)
@@ -228,18 +230,24 @@ class FlightRecorder:
                     "shared_rids": len(rids)})
         self.events.add("lane_prefill",
                         {"lane": lane, "seconds": seconds,
-                         "positions": positions, "rids": len(rids)},
+                         "positions": positions, "rids": len(rids),
+                         "prep_s": prep_s, "launch_s": launch_s,
+                         "sync_s": sync_s},
                         t=t0)
         # the event above is the slice START stamp; traceviz re-derives the
         # interval from attrs["seconds"]
 
     def on_chunk(self, lane: int, t0: float, seconds: float, steps: int,
                  entries, compiled: bool = False,
-                 segments_run=None, backend: Optional[str] = None):
+                 segments_run=None, backend: Optional[str] = None,
+                 launch_s: Optional[float] = None,
+                 sync_s: Optional[float] = None):
         """One decode dispatch (host tick: steps=1; device loop: one
         chunk).  ``entries`` is ``[(rid, tokens, exits, confs), ...]`` for
         every live slot, where tokens/exits/confs are that slot's NEW
-        values this chunk (python lists, already synced)."""
+        values this chunk (python lists, already synced).  ``seconds`` is
+        ``launch_s + sync_s``: the ``engine.decode.launch`` and
+        ``engine.decode.sync`` spans."""
         t1 = t0 + seconds
         for rid, toks, exits, confs in entries:
             f = self.live.get(rid)
@@ -259,6 +267,9 @@ class FlightRecorder:
         ev = {"lane": lane, "seconds": seconds, "steps": steps,
               "tokens": sum(len(e[1]) for e in entries),
               "compiled": bool(compiled)}
+        if launch_s is not None:
+            ev["launch_s"] = launch_s
+            ev["sync_s"] = sync_s
         if segments_run is not None:
             ev["segments_run"] = [int(x) for x in segments_run]
         if backend is not None:

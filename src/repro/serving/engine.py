@@ -55,6 +55,7 @@ from repro.core.macs import segment_macs_per_token
 from repro.launch.shard_rules import (cache_spec, constrain_carry,
                                      place_params, to_shardings)
 from repro.models.model import CascadeModel, extra_input_shapes
+from repro.obs.spans import span
 from repro.serving.batching import DepthCompactor, cohort_capacity
 from repro.serving.paged import PagedCascadeCache
 from repro.serving.runtime import DeviceDecodeLoop, kernel_provenance
@@ -438,31 +439,34 @@ class CascadeServingEngine:
     def _admit(self):
         if self.paged:
             return self._admit_paged()
-        while self.queue:
-            free = [i for i, lane in enumerate(self.lanes)
-                    if any(s.done for s in lane["slots"])]
-            if not free:
-                break
-            req = self.queue.pop(0)
-            depth = self._predict_depth(req)
-            lane_id = self.compactor.assign(depth, free)
-            lane = self.lanes[lane_id]
-            # within the lane, place the request in the cohort whose depth
-            # band matches — cohort-split skip predicates (n_cohorts > 1)
-            # only fire when a cohort's co-residents exit together
-            free_slots = [i for i, s in enumerate(lane["slots"]) if s.done]
-            slot_idx = self.compactor.pick_slot(
-                depth, free_slots, self.lane_batch, self.cohorts)
-            slot = lane["slots"][slot_idx]
-            slot.request = req
-            slot.generated = []
-            slot.exit_depths = []
-            slot.confs = []
-            slot.done = False
-            # cache is shared per-lane, so we prefill the whole lane
-            # when admission changes (simple + correct).
-            lane["dirty"] = True
-            self._record_admit(req, lane_id, slot_idx, depth)
+        with span("engine.admit"):
+            while self.queue:
+                free = [i for i, lane in enumerate(self.lanes)
+                        if any(s.done for s in lane["slots"])]
+                if not free:
+                    break
+                req = self.queue.pop(0)
+                depth = self._predict_depth(req)
+                lane_id = self.compactor.assign(depth, free)
+                lane = self.lanes[lane_id]
+                # within the lane, place the request in the cohort whose
+                # depth band matches — cohort-split skip predicates
+                # (n_cohorts > 1) only fire when a cohort's co-residents
+                # exit together
+                free_slots = [i for i, s in enumerate(lane["slots"])
+                              if s.done]
+                slot_idx = self.compactor.pick_slot(
+                    depth, free_slots, self.lane_batch, self.cohorts)
+                slot = lane["slots"][slot_idx]
+                slot.request = req
+                slot.generated = []
+                slot.exit_depths = []
+                slot.confs = []
+                slot.done = False
+                # cache is shared per-lane, so we prefill the whole lane
+                # when admission changes (simple + correct).
+                lane["dirty"] = True
+                self._record_admit(req, lane_id, slot_idx, depth)
 
     # -- paged admission --------------------------------------------------
     def _free_per_cohort(self, lane) -> List[int]:
@@ -527,7 +531,21 @@ class CascadeServingEngine:
         Head-of-queue blocking: if the head fits nowhere the queue waits
         (FIFO — keeps exit accounting comparable with the dense ablation).
         Pool exhaustion therefore backpressures admission; it can never
-        corrupt resident slots, because alloc_slot is all-or-nothing."""
+        corrupt resident slots, because alloc_slot is all-or-nothing.
+
+        ``engine.admit`` closes around each continuous admission's slot
+        prefill, which has its own ``engine.prefill.*`` spans."""
+        while True:
+            with span("engine.admit"):
+                cont = self._admit_paged_until_continuous()
+            if cont is None:
+                return
+            self._admit_continuous(*cont)
+
+    def _admit_paged_until_continuous(self):
+        """Admit whole-lane requests from the head of the queue until the
+        head joins a live lane continuously — returned as ``(lane_id, req,
+        depth)``, popped — or admission stops (None)."""
         while self.queue:
             req = self.queue[0]
             if not self.pcache.fits_ever(
@@ -544,16 +562,15 @@ class CascadeServingEngine:
                     if i not in whole and any(s.done for s in ln["slots"])]
             cands = [i for i in live if self._continuous_feasible(i, req)]
             if cands:
-                lane_id = self.compactor.assign(depth, cands)
                 # _admit_continuous records the admit itself (it knows the
                 # slot, and it may retire the request in the same call —
                 # the flight's admit span must land before its terminal)
                 self.queue.pop(0)
-                self._admit_continuous(lane_id, req, depth)
+                return self.compactor.assign(depth, cands), req, depth
             else:
                 cands = [i for i in whole if self._lane_plan_fits(i, req)]
                 if not cands:
-                    break
+                    return None
                 lane_id = self.compactor.assign(depth, cands)
                 lane = self.lanes[lane_id]
                 free_slots = [i for i, s in enumerate(lane["slots"])
@@ -570,6 +587,7 @@ class CascadeServingEngine:
                 lane["dirty"] = True
                 self.queue.pop(0)
                 self._record_admit(req, lane_id, slot_idx, depth)
+        return None
 
     def _admit_continuous(self, lane_id: int, req: Request, depth: float):
         """Prefill ``req`` into a single freed slot of a live lane.
@@ -584,80 +602,87 @@ class CascadeServingEngine:
         streams are untouched, which is the point.  Telemetry shadow rows
         for this prefill are skipped (one B=1 decision; the decode-time
         telemetry picks the slot up on its first step)."""
-        lane = self.lanes[lane_id]
-        state = lane["state"]
-        t0 = int(np.asarray(state.t))
-        P = len(req.prompt)
-        P_pad = self._pad_prompt(P)
-        free_slots = [i for i, s in enumerate(lane["slots"]) if s.done]
-        slot_idx = self.compactor.pick_slot(
-            depth, free_slots, self.lane_batch, self.cohorts,
-            free_per_cohort=self._free_per_cohort(lane))
-        self._record_admit(req, lane_id, slot_idx, depth)
-        ok = self.pcache.alloc_slot(lane_id, slot_idx, t0 - P_pad,
-                                    t0 + req.max_new_tokens)
-        assert ok, "continuous admission raced the feasibility check"
-        start = t0 - P_pad
-        toks = np.zeros((1, P_pad), np.int32)
-        toks[0, P_pad - P:] = req.prompt
-        W = self.pcache.W
-        # ring slot -> (kept token index, kept absolute position): newest
-        # position wins on ring wrap, everything unwritten stays masked
-        write_slots = np.full((W,), -1, np.int32)
-        krow = np.full((W,), -1, np.int32)
-        for p in range(max(start, t0 - W), t0):
-            write_slots[p % W] = p - start
-            krow[p % W] = p
-        tables = self.pcache.device_tables(lane_id)[
-            :, slot_idx:slot_idx + 1, :]
-        t_pre = time.perf_counter()
-        logits, new_segs = self._slot_prefill(
-            self.params, jnp.asarray(toks), self.pcache.segments,
-            jnp.asarray(start + np.arange(P_pad, dtype=np.int32)),
-            jnp.asarray(write_slots), tables, self._extra(1))
-        jax.block_until_ready(logits)
-        dt_pre = time.perf_counter() - t_pre
-        self.pcache.segments = new_segs
-        self._account_prefill(req, dt_pre, P_pad)
-        if self.flight is not None:
-            self.flight.on_prefill(lane_id, t_pre, dt_pre, [req.rid],
-                                   [req.rid], P_pad)
-        d, _ = self.decider.decide_with_carry(
-            logits, thresholds=state.thresholds,
-            state=self.decider.measure.init_state(
-                self.cfg.cascade.n_components, 1),
-            active=jnp.ones((1,), bool))
-        # merge the B=1 prefill decision into the lane's carried state:
-        # the prefill decision seeds the stateful-measure streak exactly
-        # like whole-lane prefill does (exec._carry_forward)
-        policy = state.policy
-        if policy is not None and d.state is not None:
-            policy = jax.tree_util.tree_map(
-                lambda full, one: full.at[..., slot_idx].set(one[..., 0]),
-                policy, d.state)
-        conf = float(np.asarray(d.confidence)[0])
-        ema = state.ema_conf.at[slot_idx].set(
-            (1.0 - CONF_EMA_DECAY) * conf)
-        lane["kpos"] = lane["kpos"].at[slot_idx].set(jnp.asarray(krow))
-        s = lane["slots"][slot_idx]
-        s.request = req
-        s.generated = []
-        s.exit_depths = []
-        s.confs = []
-        s.done = False
-        lane["state"] = state.replace(
-            active=jnp.asarray(self._live_mask(lane)),
-            policy=policy, ema_conf=ema,
-            block_tables=self.pcache.device_tables(lane_id))
-        self._tables_stale.discard(lane_id)
-        tok = int(np.asarray(d.prediction)[0])
-        exit_idx = int(np.asarray(d.exit_index)[0])
-        if not s.generated:
-            self.compactor.observe_prefill_exit(float(exit_idx))
-        s.generated.append(tok)
-        s.exit_depths.append(exit_idx)
-        s.confs.append(conf)
-        self._finish_if_done(s, t0, lane_id, slot_idx)
+        with span("engine.prefill.prep") as prep:
+            lane = self.lanes[lane_id]
+            state = lane["state"]
+            t0 = int(np.asarray(state.t))
+            P = len(req.prompt)
+            P_pad = self._pad_prompt(P)
+            free_slots = [i for i, s in enumerate(lane["slots"]) if s.done]
+            slot_idx = self.compactor.pick_slot(
+                depth, free_slots, self.lane_batch, self.cohorts,
+                free_per_cohort=self._free_per_cohort(lane))
+            self._record_admit(req, lane_id, slot_idx, depth)
+            ok = self.pcache.alloc_slot(lane_id, slot_idx, t0 - P_pad,
+                                        t0 + req.max_new_tokens)
+            assert ok, "continuous admission raced the feasibility check"
+            start = t0 - P_pad
+            toks = np.zeros((1, P_pad), np.int32)
+            toks[0, P_pad - P:] = req.prompt
+            W = self.pcache.W
+            # ring slot -> (kept token index, kept absolute position):
+            # newest position wins on ring wrap, everything unwritten stays
+            # masked
+            write_slots = np.full((W,), -1, np.int32)
+            krow = np.full((W,), -1, np.int32)
+            for p in range(max(start, t0 - W), t0):
+                write_slots[p % W] = p - start
+                krow[p % W] = p
+            tables = self.pcache.device_tables(lane_id)[
+                :, slot_idx:slot_idx + 1, :]
+            args = (jnp.asarray(toks), self.pcache.segments,
+                    jnp.asarray(start + np.arange(P_pad, dtype=np.int32)),
+                    jnp.asarray(write_slots), tables, self._extra(1))
+        with span("engine.prefill.launch") as launch:
+            logits, new_segs = self._slot_prefill(self.params, *args)
+        with span("engine.prefill.sync") as sync:
+            jax.block_until_ready(logits)
+        with span("engine.prefill.finish"):
+            dt_pre = launch.seconds + sync.seconds
+            self.pcache.segments = new_segs
+            self._account_prefill(req, dt_pre, P_pad)
+            if self.flight is not None:
+                self.flight.on_prefill(
+                    lane_id, launch.t0, dt_pre, [req.rid], [req.rid], P_pad,
+                    prep_s=prep.seconds, launch_s=launch.seconds,
+                    sync_s=sync.seconds)
+            d, _ = self.decider.decide_with_carry(
+                logits, thresholds=state.thresholds,
+                state=self.decider.measure.init_state(
+                    self.cfg.cascade.n_components, 1),
+                active=jnp.ones((1,), bool))
+            # merge the B=1 prefill decision into the lane's carried
+            # state: the prefill decision seeds the stateful-measure streak
+            # exactly like whole-lane prefill does (exec._carry_forward)
+            policy = state.policy
+            if policy is not None and d.state is not None:
+                policy = jax.tree_util.tree_map(
+                    lambda full, one: full.at[..., slot_idx].set(
+                        one[..., 0]),
+                    policy, d.state)
+            conf = float(np.asarray(d.confidence)[0])
+            ema = state.ema_conf.at[slot_idx].set(
+                (1.0 - CONF_EMA_DECAY) * conf)
+            lane["kpos"] = lane["kpos"].at[slot_idx].set(jnp.asarray(krow))
+            s = lane["slots"][slot_idx]
+            s.request = req
+            s.generated = []
+            s.exit_depths = []
+            s.confs = []
+            s.done = False
+            lane["state"] = state.replace(
+                active=jnp.asarray(self._live_mask(lane)),
+                policy=policy, ema_conf=ema,
+                block_tables=self.pcache.device_tables(lane_id))
+            self._tables_stale.discard(lane_id)
+            tok = int(np.asarray(d.prediction)[0])
+            exit_idx = int(np.asarray(d.exit_index)[0])
+            if not s.generated:
+                self.compactor.observe_prefill_exit(float(exit_idx))
+            s.generated.append(tok)
+            s.exit_depths.append(exit_idx)
+            s.confs.append(conf)
+            self._finish_if_done(s, t0, lane_id, slot_idx)
 
     def _finish_if_done(self, s: _Slot, pos: int, lane_id: int,
                         slot_idx: int):
@@ -771,87 +796,99 @@ class CascadeServingEngine:
         generated so far) so admission into a sibling slot never truncates a
         live sequence; the token predicted off that context is their normal
         next-step continuation."""
-        slots = lane["slots"]
-        prompts = [np.concatenate([s.request.prompt,
-                                   np.asarray(s.generated, np.int32)])
-                   if not s.done else np.zeros((1,), np.int32)
-                   for s in slots]
-        S = max(len(p) for p in prompts)
-        S = max(S, 2)
-        toks = np.zeros((self.lane_batch, S), np.int32)
-        for i, p in enumerate(prompts):
-            toks[i, -len(p):] = p          # left-pad (simplest alignment)
-        if self.paged:
-            # whole-lane re-prefill restarts every resident at the common
-            # length: release ALL the lane's reservations (no slot keeps
-            # coverage planned for the previous alignment), then claim
-            # coverage for each live slot's full span at the new one.
-            # Admission feasibility (_lane_plan_fits) guaranteed this fits.
-            for i in range(self.lane_batch):
-                self.pcache.release_slot(lane_id, i)
+        with span("engine.prefill.prep") as prep:
+            slots = lane["slots"]
+            prompts = [np.concatenate([s.request.prompt,
+                                       np.asarray(s.generated, np.int32)])
+                       if not s.done else np.zeros((1,), np.int32)
+                       for s in slots]
+            S = max(len(p) for p in prompts)
+            S = max(S, 2)
+            toks = np.zeros((self.lane_batch, S), np.int32)
+            for i, p in enumerate(prompts):
+                toks[i, -len(p):] = p      # left-pad (simplest alignment)
+            if self.paged:
+                # whole-lane re-prefill restarts every resident at the
+                # common length: release ALL the lane's reservations (no
+                # slot keeps coverage planned for the previous alignment),
+                # then claim coverage for each live slot's full span at the
+                # new one.  Admission feasibility (_lane_plan_fits)
+                # guaranteed this fits.
+                for i in range(self.lane_batch):
+                    self.pcache.release_slot(lane_id, i)
+                for i, s in enumerate(slots):
+                    if s.done:
+                        continue
+                    rem = max(1, s.request.max_new_tokens - len(s.generated))
+                    ok = self.pcache.alloc_slot(lane_id, i, 0, S + rem)
+                    assert ok, "lane prefill outgrew its admission plan"
+                lane["kpos"] = self.pcache.fresh_kpos()
+                cache_in = self.pcache.lane_cache(lane["kpos"])
+                self._tables_stale.discard(lane_id)
+            else:
+                cache_in = self._init_cache()
+            extra = self._extra(self.lane_batch)
+            # re-prefill restarts the lane's DecodeState (streaks, EMA,
+            # cursors); the prefill decision itself counts as the streak's
+            # first step.  Autotune telemetry and live thresholds are
+            # LANE-lifetime, not prefill-lifetime: carry them across the
+            # re-init (telemetry is passed INTO init_state so no zeroed
+            # counters are allocated just to be discarded).
+            old = lane.get("state")
+            state = self.executor.init_state(
+                self.lane_batch, active=self._live_mask(lane),
+                mac_weights=self.mac_prefix,
+                telemetry=(old.tel if old is not None
+                           else StagedExecutor._AUTO_TELEMETRY),
+                block_tables=(self.pcache.device_tables(lane_id)
+                              if self.paged else None))
+            if old is not None and old.thresholds is not None:
+                state = state.replace(thresholds=old.thresholds)
+            fresh_admits = [s for s in slots
+                            if not s.done and not s.generated]
+            toks = jnp.asarray(toks)
+        with span("engine.prefill.launch") as launch:
+            tok, exit_idx, conf, cache, state = self._prefill(
+                self.params, toks, cache_in, state, extra)
+        with span("engine.prefill.sync") as sync:
+            self._take_cache(lane, cache)
+            lane["state"] = state
+            tok = np.asarray(tok)
+            # the lane's first tokens are on the host: the prefill's time
+            t_tok = time.perf_counter()
+            exit_idx = np.asarray(exit_idx)
+            conf = np.asarray(conf)
+        with span("engine.prefill.finish"):
+            dt_pre = t_tok - launch.t0
+            # attribute this shared dispatch's replayed-prefix share to the
+            # newly admitted escalated requests riding in it (if any)
+            for s in fresh_admits:
+                self._account_prefill(s.request, dt_pre, self.lane_batch * S)
+            if self.flight is not None:
+                # before the slot loop below, which may retire flights
+                self.flight.on_prefill(
+                    lane_id, launch.t0, dt_pre,
+                    [s.request.rid for s in slots if not s.done],
+                    [s.request.rid for s in fresh_admits], S,
+                    prep_s=prep.seconds, launch_s=launch.seconds,
+                    sync_s=sync.seconds)
             for i, s in enumerate(slots):
-                if s.done:
-                    continue
-                rem = max(1, s.request.max_new_tokens - len(s.generated))
-                ok = self.pcache.alloc_slot(lane_id, i, 0, S + rem)
-                assert ok, "lane prefill outgrew its admission plan"
-            lane["kpos"] = self.pcache.fresh_kpos()
-            cache_in = self.pcache.lane_cache(lane["kpos"])
-            self._tables_stale.discard(lane_id)
-        else:
-            cache_in = self._init_cache()
-        extra = self._extra(self.lane_batch)
-        # re-prefill restarts the lane's DecodeState (streaks, EMA, cursors);
-        # the prefill decision itself counts as the streak's first step.
-        # Autotune telemetry and live thresholds are LANE-lifetime, not
-        # prefill-lifetime: carry them across the re-init (telemetry is
-        # passed INTO init_state so no zeroed counters are allocated just
-        # to be discarded).
-        old = lane.get("state")
-        state = self.executor.init_state(
-            self.lane_batch, active=self._live_mask(lane),
-            mac_weights=self.mac_prefix,
-            telemetry=(old.tel if old is not None
-                       else StagedExecutor._AUTO_TELEMETRY),
-            block_tables=(self.pcache.device_tables(lane_id)
-                          if self.paged else None))
-        if old is not None and old.thresholds is not None:
-            state = state.replace(thresholds=old.thresholds)
-        fresh_admits = [s for s in slots if not s.done and not s.generated]
-        t_pre = time.perf_counter()
-        tok, exit_idx, conf, cache, state = self._prefill(
-            self.params, jnp.asarray(toks), cache_in, state, extra)
-        self._take_cache(lane, cache)
-        lane["state"] = state
-        tok = np.asarray(tok)
-        dt_pre = time.perf_counter() - t_pre
-        exit_idx = np.asarray(exit_idx)
-        conf = np.asarray(conf)
-        # attribute this shared dispatch's replayed-prefix share to the
-        # newly admitted escalated requests riding in it (if any)
-        for s in fresh_admits:
-            self._account_prefill(s.request, dt_pre, self.lane_batch * S)
-        if self.flight is not None:
-            # before the slot loop below, which may retire flights
-            self.flight.on_prefill(
-                lane_id, t_pre, dt_pre,
-                [s.request.rid for s in slots if not s.done],
-                [s.request.rid for s in fresh_admits], S)
-        for i, s in enumerate(slots):
-            if not s.done:
-                if not s.generated:
-                    # warm the admission depth prior with the FIRST prefill
-                    # exit only (re-prefills of in-flight slots don't
-                    # re-count toward the prior)
-                    self.compactor.observe_prefill_exit(float(exit_idx[i]))
-                s.generated.append(int(tok[i]))
-                s.exit_depths.append(int(exit_idx[i]))
-                s.confs.append(float(conf[i]))
-                # the prefill token counts toward max_new_tokens like any
-                # decode tick — an in-flight slot near its limit may finish
-                self._finish_if_done(s, S, lane_id, i)
-        self._sync_tables(lane, lane_id)
-        lane["dirty"] = False
+                if not s.done:
+                    if not s.generated:
+                        # warm the admission depth prior with the FIRST
+                        # prefill exit only (re-prefills of in-flight slots
+                        # don't re-count toward the prior)
+                        self.compactor.observe_prefill_exit(
+                            float(exit_idx[i]))
+                    s.generated.append(int(tok[i]))
+                    s.exit_depths.append(int(exit_idx[i]))
+                    s.confs.append(float(conf[i]))
+                    # the prefill token counts toward max_new_tokens like
+                    # any decode tick — an in-flight slot near its limit
+                    # may finish
+                    self._finish_if_done(s, S, lane_id, i)
+            self._sync_tables(lane, lane_id)
+            lane["dirty"] = False
 
     def _extra(self, batch):
         shapes = extra_input_shapes(self.cfg, batch)
@@ -864,7 +901,8 @@ class CascadeServingEngine:
         token per lane (``runtime="host"``) or up to ``chunk`` tokens per
         lane inside the device loop (``runtime="device"``).  With a
         ThresholdController attached, the tick ends with its (rarely
-        firing) telemetry → solver → threshold-push check."""
+        firing) telemetry → solver → threshold-push check.  The work is
+        tiled by program spans (:mod:`repro.obs.spans`)."""
         self._tick += 1
         if self.admitting:
             self._admit()
@@ -879,7 +917,8 @@ class CascadeServingEngine:
             else:
                 self._host_tick(lane, lane_id)
         if self.controller is not None:
-            self.controller.maybe_update(self)
+            with span("engine.control"):
+                self.controller.maybe_update(self)
 
     # -- autotune surface -------------------------------------------------
     def lane_telemetry(self) -> List:
@@ -949,9 +988,7 @@ class CascadeServingEngine:
         from repro.obs.recorder import quantiles
         out = {"admission_wait_ticks": quantiles(self._admit_waits)}
         if self.flight is not None:
-            lat = self.flight.latency()
-            lat.pop("admission_wait_ticks", None)
-            out.update(lat)
+            out.update(self.flight.latency())
         else:
             out.update({"e2e_seconds": None, "per_token_seconds": None,
                         "macs_per_request": None,
@@ -996,79 +1033,102 @@ class CascadeServingEngine:
     def _host_tick(self, lane, lane_id: int):
         """Decode ONE token for every live slot of a lane (one dispatch +
         one host sync per token)."""
-        last = [s.generated[-1] if not s.done else 0
-                for s in lane["slots"]]
-        token = jnp.asarray(np.array(last, np.int32)[:, None])
-        live = self._live_mask(lane)
-        state = lane["state"].replace(active=jnp.asarray(live))
-        run_before = np.asarray(state.segments_run)
-        if self.paged:
-            self.pcache.pool.begin_chunk()
-        t0 = time.perf_counter()
-        tok, exit_idx, conf, cache, state = self._decode(
-            self.params, token, self._lane_cache(lane), state,
-            self._extra(self.lane_batch))
-        tok = np.asarray(tok)              # forces device sync
-        exit_idx = np.asarray(exit_idx)
-        conf = np.asarray(conf)
-        dt = time.perf_counter() - t0
-        n_live = int(live.sum())
-        warm = self._decode_warm
-        if warm:
-            self._decode_seconds += dt
-            self._decode_tokens += n_live
-        else:                              # first dispatch pays compilation
-            self._compile_seconds += dt
-            self._decode_warm = True
-        self._take_cache(lane, cache)
-        lane["state"] = state
-        depths = exit_idx[live]
-        ran = np.asarray(state.segments_run) - run_before
-        if self.flight is not None:
-            # stamped around the dispatch that just synced — the slot loop
-            # below may retire flights, so the chunk span lands first
-            self.flight.on_chunk(
-                lane_id, t0, dt, 1,
-                [(s.request.rid, [int(tok[i])], [int(exit_idx[i])],
-                  [float(conf[i])])
-                 for i, s in enumerate(lane["slots"]) if not s.done],
-                compiled=not warm, segments_run=ran)
-        if warm:
-            # the warm-up dispatch is excluded from EVERY window metric
-            # (MAC, skip, opportunity, wallclock) so stats() rates all
-            # cover the same steps; its tokens still reach the slots below
-            self._account(lane_id, depths, n_live, ran, steps=1,
-                          max_depths=[int(depths.max()) if n_live else 0])
-        for i, s in enumerate(lane["slots"]):
-            if s.done:
-                continue
-            s.generated.append(int(tok[i]))
-            s.exit_depths.append(int(exit_idx[i]))
-            s.confs.append(float(conf[i]))
-            self._finish_if_done(s, int(state.t), lane_id, i)
-        self._sync_tables(lane, lane_id)
-        if self.paged:
-            self.pcache.pool.end_chunk()
+        with span("engine.decode.prep"):
+            last = [s.generated[-1] if not s.done else 0
+                    for s in lane["slots"]]
+            token = jnp.asarray(np.array(last, np.int32)[:, None])
+            live = self._live_mask(lane)
+            state = lane["state"].replace(active=jnp.asarray(live))
+            run_before = np.asarray(state.segments_run)
+            if self.paged:
+                self.pcache.pool.begin_chunk()
+            cache_in = self._lane_cache(lane)
+            extra = self._extra(self.lane_batch)
+        with span("engine.decode.launch") as launch:
+            tok, exit_idx, conf, cache, state = self._decode(
+                self.params, token, cache_in, state, extra)
+        with span("engine.decode.sync") as sync:
+            tok = np.asarray(tok)          # forces device sync
+            exit_idx = np.asarray(exit_idx)
+            conf = np.asarray(conf)
+        with span("engine.decode.finish"):
+            dt = launch.seconds + sync.seconds
+            n_live = int(live.sum())
+            warm = self._decode_warm
+            if warm:
+                self._decode_seconds += dt
+                self._decode_tokens += n_live
+            else:                          # first dispatch pays compilation
+                self._compile_seconds += dt
+                self._decode_warm = True
+            self._take_cache(lane, cache)
+            lane["state"] = state
+            depths = exit_idx[live]
+            ran = np.asarray(state.segments_run) - run_before
+            if self.flight is not None:
+                # stamped around the dispatch that just synced — the slot
+                # loop below may retire flights, so the chunk span lands
+                # first
+                self.flight.on_chunk(
+                    lane_id, launch.t0, dt, 1,
+                    [(s.request.rid, [int(tok[i])], [int(exit_idx[i])],
+                      [float(conf[i])])
+                     for i, s in enumerate(lane["slots"]) if not s.done],
+                    compiled=not warm, segments_run=ran,
+                    launch_s=launch.seconds, sync_s=sync.seconds)
+            if warm:
+                # the warm-up dispatch is excluded from EVERY window metric
+                # (MAC, skip, opportunity, wallclock) so stats() rates all
+                # cover the same steps; its tokens still reach the slots
+                # below
+                self._account(lane_id, depths, n_live, ran, steps=1,
+                              max_depths=[int(depths.max()) if n_live
+                                          else 0])
+            pos = int(state.t)
+            for i, s in enumerate(lane["slots"]):
+                if s.done:
+                    continue
+                s.generated.append(int(tok[i]))
+                s.exit_depths.append(int(exit_idx[i]))
+                s.confs.append(float(conf[i]))
+                self._finish_if_done(s, pos, lane_id, i)
+            self._sync_tables(lane, lane_id)
+            if self.paged:
+                self.pcache.pool.end_chunk()
 
     def _device_tick(self, lane, lane_id: int):
         """Decode up to ``chunk`` tokens for a lane inside the device
         while_loop — one dispatch and ONE host sync per chunk; finished
         slots drain from the returned buffers."""
-        slots = lane["slots"]
-        last = [s.generated[-1] if not s.done else 0 for s in slots]
-        token = np.array(last, np.int32)[:, None]
-        live = self._live_mask(lane)
-        remaining = np.array(
-            [s.request.max_new_tokens - len(s.generated) if not s.done else 0
-             for s in slots], np.int32)
-        # the host mask rides into the loop as is (placed by its sharding)
-        state = lane["state"].replace(active=live)
-        run_before = np.asarray(state.segments_run)
-        if self.paged:
-            self.pcache.pool.begin_chunk()
+        with span("engine.decode.prep"):
+            slots = lane["slots"]
+            last = [s.generated[-1] if not s.done else 0 for s in slots]
+            token = np.array(last, np.int32)[:, None]
+            live = self._live_mask(lane)
+            remaining = np.array(
+                [s.request.max_new_tokens - len(s.generated)
+                 if not s.done else 0 for s in slots], np.int32)
+            # the host mask rides into the loop as is (placed by its
+            # sharding)
+            state = lane["state"].replace(active=live)
+            run_before = np.asarray(state.segments_run)
+            if self.paged:
+                self.pcache.pool.begin_chunk()
+            cache_in = self._lane_cache(lane)
+            extra = self._extra(self.lane_batch)
+        # the loop's own spans: the rest of prep (placing the host
+        # vectors), launch and sync
         chunk, cache, state = self.loop.run_chunk(
-            self.params, token, self._lane_cache(lane), state, remaining,
-            self._extra(self.lane_batch))
+            self.params, token, cache_in, state, remaining, extra)
+        with span("engine.decode.finish"):
+            self._device_finish(lane, lane_id, chunk, cache, state,
+                                run_before)
+
+    def _device_finish(self, lane, lane_id: int, chunk, cache, state,
+                       run_before):
+        """A chunk's host bookkeeping: adopt the carry, account the
+        window, file the recorder's slices, hand tokens to the slots."""
+        slots = lane["slots"]
         self._take_cache(lane, cache)
         lane["state"] = state
         n = chunk.n_steps
@@ -1082,6 +1142,9 @@ class CascadeServingEngine:
             if self.paged:
                 self.pcache.pool.end_chunk()
             return
+        # the chunk's segment-execution deltas: one read, for the recorder
+        # and the accounting both
+        ran = np.asarray(state.segments_run) - run_before
         if self.flight is not None:
             entries = []
             for i, s in enumerate(slots):
@@ -1095,12 +1158,11 @@ class CascadeServingEngine:
                     [float(chunk.confs[r, i]) for r in rows]))
             self.flight.on_chunk(
                 lane_id, chunk.t_host, chunk.seconds, n, entries,
-                compiled=chunk.compiled,
-                segments_run=np.asarray(state.segments_run) - run_before)
+                compiled=chunk.compiled, segments_run=ran,
+                launch_s=chunk.launch_s, sync_s=chunk.sync_s)
         if not chunk.compiled:
             # like the host tick: the compile chunk is excluded from every
             # window metric so all stats() rates cover the same steps
-            ran = np.asarray(state.segments_run) - run_before
             max_depths = []
             for step in range(n):
                 d = chunk.exits[step][chunk.live[step]]

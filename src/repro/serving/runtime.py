@@ -42,7 +42,6 @@ budget vectors batch-sharded).
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Optional
 
 import jax
@@ -51,9 +50,7 @@ import numpy as np
 
 from repro.launch.shard_rules import decode_loop_in_specs, to_shardings
 from repro.launch.steps import make_decode_loop_step
-from repro.utils import get_logger
-
-log = get_logger("serving.runtime")
+from repro.obs.spans import span
 
 
 def kernel_provenance(cfg) -> dict:
@@ -77,11 +74,14 @@ class DecodeChunk:
     ``live`` marks the slots that were still generating when step i's token
     was produced (a slot's valid outputs are exactly its True rows).
     ``seconds`` is the host-measured wall-clock of the dispatch including
-    the single per-chunk sync; ``t_host`` is the ``perf_counter`` stamp at
-    dispatch start (so the flight recorder can place the chunk's slice on
-    a wall-clock timeline without adding any sync of its own); ``compiled``
-    marks the warm-up call that paid jit compilation (callers should
-    report its time as compile cost, not decode cost).
+    the single per-chunk sync: ``launch_s`` (the ``engine.decode.launch``
+    span, the jitted call until it returns) plus ``sync_s`` (the
+    ``engine.decode.sync`` span, the batched ``device_get`` and the
+    trim); ``t_host`` is the ``perf_counter`` stamp at dispatch start (so
+    the flight recorder can place the chunk's slice on a wall-clock
+    timeline without adding any sync of its own); ``compiled`` marks the
+    warm-up call that paid jit compilation (callers should report its time
+    as compile cost, not decode cost).
     """
 
     tokens: np.ndarray
@@ -93,6 +93,8 @@ class DecodeChunk:
     seconds: float
     compiled: bool
     t_host: float = 0.0
+    launch_s: float = 0.0
+    sync_s: float = 0.0
 
 
 class DeviceDecodeLoop:
@@ -130,7 +132,6 @@ class DeviceDecodeLoop:
         self._jitted = None
         # where token, state and remaining go before a chunk's clock starts
         self._host_in = None
-        self.compile_seconds = 0.0
         self._warm = False
 
     # ------------------------------------------------------------------
@@ -159,33 +160,36 @@ class DeviceDecodeLoop:
         ``(DecodeChunk, new_cache, new_state)``; the passed cache/state are
         donated and must not be reused.
         """
-        token = np.asarray(token, np.int32)
-        remaining = np.asarray(remaining, np.int32)
-        if self._jitted is None:
-            self._jitted = self._build(params, cache, state, token.shape[0])
-        # the small host vectors (token, remaining, the active mask in
-        # state) go straight to the loop's input layout, outside the timed
-        # span: a plain device copy would sit on one device of a mesh
-        token, state, remaining = jax.device_put((token, state, remaining),
-                                                 self._host_in)
-        t0 = time.perf_counter()
-        (toks, exits, confs, live, n_steps, cache, state,
-         rem) = self._jitted(params, token, cache, state, remaining, extra)
-        # the ONE host sync per chunk: a single batched device_get of the
-        # small (K, B) buffers + counters (cache/state stay on device)
-        n, toks, exits, confs, live, rem = jax.device_get(
-            (n_steps, toks, exits, confs, live, rem))
-        n = int(n)
-        toks, exits, confs, live = (toks[:n], exits[:n], confs[:n], live[:n])
-        seconds = time.perf_counter() - t0
+        with span("engine.decode.prep"):
+            token = np.asarray(token, np.int32)
+            remaining = np.asarray(remaining, np.int32)
+            if self._jitted is None:
+                self._jitted = self._build(params, cache, state,
+                                           token.shape[0])
+            # the small host vectors (token, remaining, the active mask in
+            # state) go straight to the loop's input layout, outside the
+            # timed spans: a plain device copy would sit on one device of a
+            # mesh
+            token, state, remaining = jax.device_put(
+                (token, state, remaining), self._host_in)
+        with span("engine.decode.launch") as launch:
+            (toks, exits, confs, live, n_steps, cache, state,
+             rem) = self._jitted(params, token, cache, state, remaining,
+                                 extra)
+        with span("engine.decode.sync") as sync:
+            # the ONE host sync per chunk: a single batched device_get of
+            # the small (K, B) buffers + counters (cache/state stay on
+            # device)
+            n, toks, exits, confs, live, rem = jax.device_get(
+                (n_steps, toks, exits, confs, live, rem))
+            n = int(n)
+            toks, exits, confs, live = (toks[:n], exits[:n], confs[:n],
+                                        live[:n])
         compiled = not self._warm
-        if compiled:
-            self._warm = True
-            self.compile_seconds += seconds
-            log.debug("decode loop compiled in %.3fs (chunk=%d)",
-                      seconds, self.chunk)
+        self._warm = True
         return (DecodeChunk(tokens=toks, exits=exits, confs=confs,
                             live=live, n_steps=n, remaining=rem,
-                            seconds=seconds, compiled=compiled,
-                            t_host=t0),
+                            seconds=launch.seconds + sync.seconds,
+                            compiled=compiled, t_host=launch.t0,
+                            launch_s=launch.seconds, sync_s=sync.seconds),
                 cache, state)

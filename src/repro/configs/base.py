@@ -385,9 +385,11 @@ class KernelTuneConfig:
     logits never reach HBM.  Heads outside the fusion boundary
     (layernorm bias, enhancement MLP) transparently fall back to the
     unfused path.  ``cohort_scatter`` replaces the mixed-exit cohort
-    re-join (per-cohort slice + ``concatenate``) with the aliased Pallas
-    scatter kernel (:mod:`repro.kernels.cohort_cache`) that writes each
-    cohort's cache rows in place.  Both default off: decode streams are
+    re-join (per-cohort slice + ``concatenate``) of caches that come back
+    whole (recurrent states) with the aliased Pallas scatter kernel
+    (:mod:`repro.kernels.cohort_cache`) that writes each cohort's cache
+    rows in place; dense rings come back as one decode row per layer,
+    which a concat joins.  Both default off: decode streams are
     pinned bit-identical either way, but flipping them changes the
     traced graph.
     """

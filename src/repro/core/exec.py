@@ -43,8 +43,9 @@ copies — see :meth:`StagedExecutor.decode_step`):
   cohort-major cache views.  The per-cohort slice/re-join machinery only
   runs when cohorts actually disagree.
 * ``"copy"`` — the legacy layout: every segment re-slices the batch per
-  cohort and re-concatenates hidden state, carry and the full segment
-  cache, whatever the exit state.  Kept as the ablation baseline the
+  cohort and re-concatenates hidden state, carry and the segment's cache
+  output (dense rings' decode rows, recurrent states whole), whatever the
+  exit state.  Kept as the ablation baseline the
   layout benchmark (``benchmarks/bench_llm_cascade.py``) measures against.
 
 The per-slot ``DecodeState.active`` mask also rides in the decode context
@@ -76,6 +77,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core.policy import ExitDecider, ExitDecision
+from repro.models.blocks import RingRows
 
 # EMA decay for the per-slot answering-confidence telemetry carried in
 # DecodeState (same decay as DepthCompactor's host-side depth prior).
@@ -344,9 +346,27 @@ class StagedExecutor:
         its ``backfill{si}`` scope); the caches as they are without
         ``cascade.state_backfill``."""
         if not self.cfg.cascade.state_backfill:
-            return seg_cache
+            return self.model.hold_segment(si, ctx, seg_cache)
         with jax.named_scope(f"backfill{si}"):
             return self.model.backfill_segment(si, params, h, ctx, seg_cache)
+
+    def _rejoin(self, stage_cache, parts):
+        """One stage's cache from its C cohorts' outputs.  Dense rings'
+        decode rows (:class:`~repro.models.blocks.RingRows`, one token's
+        row per layer) are concatenated.  Caches that come back whole
+        (recurrent states) are too, or with
+        ``cfg.kernel_tune.cohort_scatter`` written as C aliased partial
+        writes into the input slab (bit-identical; XLA does not elide the
+        concat's full-slab materialization inside while+cond)."""
+        if self.use_cohort_scatter and not isinstance(parts[0], RingRows):
+            from repro.kernels.ops import cohort_scatter_tree
+            for c, part in enumerate(parts):
+                stage_cache = cohort_scatter_tree(
+                    stage_cache, part, c, len(parts),
+                    interpret=self.cfg.kernel_interpret)
+            return stage_cache
+        return jax.tree_util.tree_map(
+            lambda *xs: jnp.concatenate(xs, axis=1), *parts)
 
     # ------------------------------------------------------------------
     def _segment_paths(self, si, ctx_c, params, ths):
@@ -616,15 +636,7 @@ class StagedExecutor:
                     # paged: no batch dim to view — the SHARED store chains
                     # through the cohorts, each addressing it through its
                     # own table rows (ctx_parts carry the sliced tables).
-                    # the dense re-join is either the legacy concat or, with
-                    # cfg.kernel_tune.cohort_scatter, C aliased partial
-                    # writes into the input slab (bit-identical; PR 4
-                    # documented XLA does not elide the concat's full-slab
-                    # materialization inside while+cond)
-                    scatter = self.use_cohort_scatter and not paged
-                    if scatter:
-                        from repro.kernels.ops import cohort_scatter_tree
-                        scat = seg
+                    # the dense re-join is per stage (_rejoin)
                     if not paged:
                         view = jax.tree_util.tree_map(
                             lambda x: x.reshape((x.shape[0], C, Bc)
@@ -644,20 +656,14 @@ class StagedExecutor:
                             hsp[c] = hs_c
                         if paged:
                             seg = nc_c
-                        elif scatter:
-                            scat = cohort_scatter_tree(
-                                scat, nc_c, c, C,
-                                interpret=self.cfg.kernel_interpret)
                         else:
                             parts.append(nc_c)
                         r = r + rc
                     if paged:
                         nc = seg
-                    elif scatter:
-                        nc = scat
                     else:
-                        nc = jax.tree_util.tree_map(
-                            lambda *xs: jnp.concatenate(xs, axis=1), *parts)
+                        nc = [self._rejoin(st, ps)
+                              for st, ps in zip(seg, zip(*parts))]
                     return hp, nc, scp, r, hsp
 
                 def _all_run(hp, seg, scp, hsp, _si=si):

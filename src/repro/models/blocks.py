@@ -9,6 +9,14 @@ A block kind provides:
         state WITHOUT computing the layer's output — the cheap path that keeps
         deeper caches coherent when a token exits early.)
 
+In a decode step over a dense self-attention ring (:func:`in_place_ring`)
+the ``cache`` a block gets is a read-only :class:`RingView` of its layer's
+ring and the ``new_cache`` it returns is the token's :class:`RingRows`:
+decode writes one K and one V row per layer, and attention reads the ring
+as it stands after that write (the row selected in at the step's slot).
+The model writes the rows into the stacked caches in place
+(``CascadeModel.commit_decode``).
+
 ``ctx`` carries everything invariant across the layers of a stage:
   mode: "full" | "decode"      (static, via closure)
   positions: (B,S) absolute positions of the current tokens (full mode)
@@ -21,7 +29,7 @@ A block kind provides:
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -68,12 +76,75 @@ def _write_full(cache, k, v, gather_idx):
     return {"k": ck, "v": cv}
 
 
+class RingView(NamedTuple):
+    """One layer's dense ring as a decode step over stacked caches sees
+    it: ``k`` / ``v`` (B, W, kv, hd), as stored before the step and
+    read-only.  A block handed a ``RingView`` returns :class:`RingRows`
+    in place of a rewritten ring."""
+    k: jax.Array
+    v: jax.Array
+
+
+class RingRows(NamedTuple):
+    """The K/V rows a decode step writes into dense rings: (B, 1, kv, hd)
+    from one layer, (L, B, 1, kv, hd) from a stage's layer scan.  They are
+    written into the stacked caches in place once the step's segments
+    have run (:meth:`CascadeModel.commit_decode`, :func:`write_rows`)."""
+    k: jax.Array
+    v: jax.Array
+
+
+def in_place_ring(block: "BlockDef", ctx) -> bool:
+    """Whether a decode step hands this block kind :class:`RingView` and
+    takes back :class:`RingRows`: a dense self-attention ring
+    (``attn_cache_init``'s ``{"k", "v"}`` with a ``W`` axis) outside the
+    paged layout.  Prefill, recurrent states, cross-attention caches and
+    paged stores keep a per-layer cache that the block rewrites."""
+    return (ctx["mode"] == "decode" and ctx.get("block_table") is None
+            and block.init_cache is attn_cache_init)
+
+
+def write_rows(stacked, rows: RingRows, slot):
+    """Write a stage's decode rows into its stacked (L, B, W, kv, hd) ring
+    at ``slot``: one row per layer, in place; every other slot keeps its
+    bytes."""
+    return {n: lax.dynamic_update_slice(
+        stacked[n], getattr(rows, n).astype(stacked[n].dtype),
+        (0, 0, slot, 0, 0)) for n in ("k", "v")}
+
+
+def read_rows(stacked, slot) -> RingRows:
+    """The rows a stage's stacked ring holds at ``slot`` (writing them
+    back leaves the ring as it is)."""
+    L, B, _, kv, hd = stacked["k"].shape
+    return RingRows(*(lax.dynamic_slice(stacked[n], (0, 0, slot, 0, 0),
+                                        (L, B, 1, kv, hd))
+                      for n in ("k", "v")))
+
+
 def _write_decode(cache, k, v, slot):
+    """One decode token's K/V (B, 1, kv, hd) for ring slot ``slot``: a
+    per-layer ring comes back rewritten; a :class:`RingView` gives back
+    just the :class:`RingRows`."""
+    if isinstance(cache, RingView):
+        return RingRows(k.astype(cache.k.dtype), v.astype(cache.v.dtype))
     ck = lax.dynamic_update_slice(
         cache["k"], k.astype(cache["k"].dtype), (0, slot, 0, 0))
     cv = lax.dynamic_update_slice(
         cache["v"], v.astype(cache["v"].dtype), (0, slot, 0, 0))
     return {"k": ck, "v": cv}
+
+
+def _ring_kv(cache, new_cache, slot):
+    """The (B, W, kv, hd) K and V rings a decode step attends over: the
+    ring with this step's row written.  For a :class:`RingView` that is
+    the row selected over the stored ring at ``slot``: the same values,
+    read where the ring lies."""
+    if isinstance(cache, RingView):
+        at = jnp.arange(cache.k.shape[1])[None, :, None, None] == slot
+        return (jnp.where(at, new_cache.k, cache.k),
+                jnp.where(at, new_cache.v, cache.v))
+    return new_cache["k"], new_cache["v"]
 
 
 # ---------------------------------------------------------------------------
@@ -173,7 +244,7 @@ def _self_attention(cfg, params, h, ctx, cache):
             kv_k, kv_v = _paged_kv_view(cfg, new_cache, table)
         else:
             new_cache = _write_decode(cache, k, v, slot)
-            kv_k, kv_v = new_cache["k"], new_cache["v"]
+            kv_k, kv_v = _ring_kv(cache, new_cache, slot)
         # dense: lane-wide (W,) ring; paged: per-slot (B, W) ring
         kpos = ctx["kpos"].at[..., slot].set(t)
         if cfg.use_kernels and q.shape[-1] % 8 == 0:
@@ -336,7 +407,8 @@ def shared_attn_apply(cfg, params, h, ctx, cache):
         k = apply_rope(k, jnp.full((1, 1), t), cfg.rope_theta)
         new_cache = _write_decode(cache, k, v, ctx["slot"])
         kpos = ctx["kpos"].at[..., ctx["slot"]].set(t)
-        out = attend_decode(q, new_cache["k"], new_cache["v"], t, kpos)
+        out = attend_decode(q, *_ring_kv(cache, new_cache, ctx["slot"]),
+                            t, kpos)
     out = out.reshape(B, S, -1) @ attn_p["wo"].astype(x.dtype)
     h = h + out
     m = mlp_apply(shared["mlp"], cfg, norm_apply(shared["mlp"]["norm"], cfg, h))
@@ -524,17 +596,14 @@ def _no_backfill(cfg, params, h, ctx, cache):
 
 
 BLOCKS: Dict[str, BlockDef] = {
-    "dense": BlockDef(dense_init_block, dense_apply,
-                      lambda cfg, b, W, dt: attn_cache_init(cfg, b, W, dt),
+    "dense": BlockDef(dense_init_block, dense_apply, attn_cache_init,
                       dense_backfill),
-    "moe": BlockDef(moe_init_block, moe_apply_block,
-                    lambda cfg, b, W, dt: attn_cache_init(cfg, b, W, dt),
+    "moe": BlockDef(moe_init_block, moe_apply_block, attn_cache_init,
                     dense_backfill),
     "mamba": BlockDef(mamba_init_block, mamba_apply, mamba_cache,
                       mamba_backfill),
     "attn_shared": BlockDef(shared_attn_init, shared_attn_apply,
-                            lambda cfg, b, W, dt: attn_cache_init(cfg, b, W, dt),
-                            shared_attn_backfill),
+                            attn_cache_init, shared_attn_backfill),
     "mlstm": BlockDef(mlstm_init_block, mlstm_apply, mlstm_cache,
                       mlstm_backfill),
     "slstm": BlockDef(slstm_init_block, slstm_apply, slstm_cache,

@@ -20,6 +20,15 @@ Public entry points:
   decode_step(params, token, t, cache, extra)    -> (exit_logits, cache)
   decode(params, token, cache, state, extra)     -> (decision, cache, state)
 
+A decode step writes exactly one K and one V row per layer into each dense
+ring cache: the layer scan hands every attention layer its stored ring as a
+read-only :class:`~repro.models.blocks.RingView`, the layer attends over it
+with its own row selected in at the step's slot (the ring as it reads after
+the write), and the scan returns only the rows
+(:class:`~repro.models.blocks.RingRows`), which :meth:`commit_decode` writes
+into the stacked caches in place once the step's segments have run.  Prefill,
+recurrent states, cross-attention and paged caches are rewritten per layer.
+
 ``decode_step`` is the dense reference: it computes every segment and returns
 every exit's logits (what the prefill/decode consistency tests pin).  The
 *staged* decode — ``cfg.cascade.exit_mode`` "select" | "cond_batch", carrying
@@ -41,7 +50,8 @@ from jax import lax
 
 from repro.configs.base import ModelConfig
 from repro.models import nn
-from repro.models.blocks import BLOCKS, layer_kinds
+from repro.models.blocks import (BLOCKS, RingRows, RingView, in_place_ring,
+                                 layer_kinds, read_rows, write_rows)
 from repro.models.layers import attn_init, mlp_init, norm_apply, norm_init
 from repro.utils import dtype_of
 
@@ -143,13 +153,23 @@ class CascadeModel:
         return int(n)
 
     def _run_stage(self, kind, stacked, h, ctx, stacked_cache, remat=False):
+        """Scan one stage's layers.  In a decode step over a dense ring
+        cache (:func:`~repro.models.blocks.in_place_ring`) each layer
+        attends over its stored ring with this step's row selected in, and
+        the stage returns only :class:`~repro.models.blocks.RingRows`, one
+        K/V row per layer, which :meth:`commit_decode` writes in place.
+        Other caches go through the scan whole, one layer's slice each."""
         block = BLOCKS[kind]
         has_cache = stacked_cache is not None
 
         unroll = self._unroll(stacked)
         if has_cache:
+            rows = in_place_ring(block, ctx)
+
             def body(h, xs):
                 pa, ca = xs
+                if rows:
+                    ca = RingView(ca["k"], ca["v"])
                 h2, c2, aux = block.apply(self.cfg, pa, h, ctx, ca)
                 return h2, (c2, aux)
             h, (new_cache, auxs) = lax.scan(body, h, (stacked, stacked_cache),
@@ -189,7 +209,9 @@ class CascadeModel:
 
     def _backfill_segment(self, si, params, h, ctx, seg_cache):
         """Cheap path: update caches of segment si from the exit hidden state
-        without computing the segment output (cascade state backfill)."""
+        without computing the segment output (cascade state backfill).  A
+        decode step over dense rings computes only each layer's K/V row
+        (:class:`~repro.models.blocks.RingRows`) and reads no ring."""
         if ctx.get("block_tables") is not None:
             ctx = {**ctx, "block_table": ctx["block_tables"][si]}
         new_caches = []
@@ -197,9 +219,12 @@ class CascadeModel:
             block = BLOCKS[kind]
             stacked = params["segments"][si][pi]
             cache_i = seg_cache[pi]
+            rows = in_place_ring(block, ctx)
 
             def body(h_const, xs):
                 pa, ca = xs
+                if rows:
+                    ca = RingView(ca["k"], ca["v"])
                 c2 = block.backfill(self.cfg, pa, h_const, ctx, ca)
                 return h_const, c2
             _, nc = lax.scan(body, h, (stacked, cache_i),
@@ -216,6 +241,17 @@ class CascadeModel:
         """Write segment ``si``'s caches from the exit hidden state without
         computing the segment (the skip path's cache-coherence write)."""
         return self._backfill_segment(si, params, h, ctx, seg_cache)
+
+    def hold_segment(self, si, ctx, seg_cache):
+        """Segment ``si``'s caches left as they are, in the form
+        ``run_segment`` / ``backfill_segment`` return them in this step:
+        dense rings give back the rows already stored at the step's slot,
+        which :meth:`commit_decode` writes back unchanged."""
+        if ctx.get("block_tables") is not None:
+            ctx = {**ctx, "block_table": ctx["block_tables"][si]}
+        return [read_rows(c, ctx["slot"])
+                if in_place_ring(BLOCKS[kind], ctx) else c
+                for (kind, _), c in zip(self.segment_runs[si], seg_cache)]
 
     # ------------------------------------------------------------------
     # heads
@@ -428,14 +464,20 @@ class CascadeModel:
         return h, ctx
 
     def commit_decode(self, cache, new_segs, t):
-        """Finish a decode step: record position t in the kpos ring (the
-        lane-wide (W,) ring, or every slot's row of the paged per-slot
+        """Finish a decode step: write each dense ring's
+        :class:`~repro.models.blocks.RingRows` into its stacked cache at
+        slot ``t % W`` (one K and one V row per layer, in place, outside
+        any of the step's branches) and record position t in the kpos ring
+        (the lane-wide (W,) ring, or every slot's row of the paged per-slot
         (B, W) ring — dead slots' rows are masked by the kernels' live
         mask and re-planned at admission, so the broadcast is safe)."""
         W = cache["kpos"].shape[-1]
         slot = jnp.asarray(t, jnp.int32) % W
+        segs = [[write_rows(old, new, slot) if isinstance(new, RingRows)
+                 else new for old, new in zip(old_seg, new_seg)]
+                for old_seg, new_seg in zip(cache["segments"], new_segs)]
         kpos = cache["kpos"].at[..., slot].set(jnp.asarray(t, jnp.int32))
-        return {"kpos": kpos, "segments": new_segs}
+        return {"kpos": kpos, "segments": segs}
 
     def decode_step(self, params, token, t, cache, extra=None):
         """One DENSE decode step: every segment computes, every exit's

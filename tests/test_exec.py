@@ -11,7 +11,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config, reduced
 from repro.core.exec import DecodeState, StagedExecutor, init_decode_state
-from repro.core.policy import BudgetPolicy, ExitDecider
+from repro.core.policy import (BudgetPolicy, ConfidenceMeasure, ExitDecider,
+                               register_measure)
 from repro.launch.shard_rules import (batch_spec, cache_spec,
                                       decode_state_spec, param_spec,
                                       to_shardings)
@@ -285,3 +286,131 @@ def test_decode_state_pytree_roundtrip():
     assert int(st2.t) == 7 and st2.policy.shape == (2, 3)
     st3 = st.replace(t=jnp.asarray(9, jnp.int32))
     assert int(st3.t) == 9 and int(st.t) == 7
+
+
+# ---------------------------------------------------------------------------
+# in-place decode rows: one K/V row per layer into the stacked caches
+# ---------------------------------------------------------------------------
+
+@register_measure("exec_first_half")
+class _FirstHalfMeasure(ConfidenceMeasure):
+    """Confident iff the row lies in the first half of the rows measured:
+    with two cohorts of two rows, cohort 0 exits at component 0 and cohort
+    1 runs on, so each deep segment takes the mixed branch."""
+
+    name = "exec_first_half"
+
+    def __init__(self, arg: str = ""):
+        del arg
+
+    def __call__(self, logits):
+        half = jnp.arange(logits.shape[0]) < logits.shape[0] // 2
+        return jnp.argmax(logits, axis=-1), half.astype(jnp.float32)
+
+
+def _one_step(cfg, params, toks, W):
+    """Prefill + one staged decode step: (cache before, token, decision,
+    cache after, segments run by the step)."""
+    model = build_model(cfg)
+    ex = StagedExecutor(model, cfg)
+    d, cache, state = ex.prefill(params, toks, model.init_cache(
+        toks.shape[0], W))
+    token = d.prediction[:, None]
+    d1, cache1, state1 = jax.jit(ex.decode_step)(params, token, cache, state)
+    ran = np.asarray(state1.segments_run) - np.asarray(state.segments_run)
+    return cache, token, d1, cache1, ran
+
+
+def _layer(stacked, l):
+    return jax.tree_util.tree_map(lambda x: x[l], stacked)
+
+
+@pytest.mark.parametrize("branch,n_cohorts,backfill", [
+    ("run", 1, True), ("run", 2, True),
+    ("skip", 1, True), ("skip", 2, True),
+    ("mixed", 2, True), ("skip", 2, False),
+])
+def test_decode_writes_one_row_per_layer(monkeypatch, branch, n_cohorts,
+                                         backfill):
+    """One staged decode step writes, into every layer of each segment's
+    stacked cache, only row ``t % W``: the K/V ``qkv_project`` makes from
+    the layer's input where the segment ran, ``_attn_backfill``'s from the
+    exit hidden state where it was skipped (nothing without
+    ``state_backfill``), to float32 rounding of the eager reference.
+    Every other slot keeps its bytes, and tokens, exits, confidences and
+    caches equal, bit for bit, the per-layer ring path's (each layer
+    rewriting its whole ring through the scan)."""
+    from repro.models import model as model_mod
+    from repro.models.blocks import BLOCKS, _attn_backfill
+    from repro.models.layers import norm_apply, qkv_project
+
+    measure, ths = {"run": ("softmax_max", (1.1, 1.1, 0.0)),
+                    "skip": ("softmax_max", (0.0, 0.0, 0.0)),
+                    "mixed": ("exec_first_half", (0.5, 0.5, 0.0))}[branch]
+    cfg = reduced(get_config("qwen2.5-3b"), n_layers=6).with_cascade(
+        n_components=3, exit_boundaries=(2, 4), thresholds=ths,
+        confidence=measure, exit_mode="cond_batch", n_cohorts=n_cohorts,
+        state_backfill=backfill)
+    params = build_model(cfg).init(jax.random.PRNGKey(0))
+    rng = np.random.default_rng(0)
+    B, S, W = 4, 10, 8          # the ring wraps: slot S % W holds data
+    toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, S)), jnp.int32)
+    cache0, token, d, cache, ran = _one_step(cfg, params, toks, W)
+    # rows of the cohorts that ran each deep segment
+    runs = {"run": [True] * B, "skip": [False] * B,
+            "mixed": [False] * (B // 2) + [True] * (B // 2)}[branch]
+    runs = np.asarray(runs)
+    np.testing.assert_array_equal(
+        ran, [n_cohorts] + [int(runs.sum()) // (B // n_cohorts)] * 2)
+
+    # plain reference: layer by layer over per-layer rings
+    model = build_model(cfg)
+    h, ctx = model.begin_decode(params, token, S, cache0)
+    slot = S % W
+    rope_t = jnp.full((1, 1), S)
+    h_exit = None
+    for si in range(3):
+        stage, ring = params["segments"][si][0], cache0["segments"][si][0]
+        got = cache["segments"][si][0]
+        for l in range(stage["attn"]["wk"].shape[0]):
+            pa, ca = _layer(stage, l), _layer(ring, l)
+            x = norm_apply(pa["attn"]["norm"], cfg, h)
+            _, k_run, v_run = qkv_project(pa["attn"], cfg, x,
+                                          rope_positions=rope_t)
+            if si == 0:
+                want_k, want_v = k_run, v_run
+            else:
+                src = (_attn_backfill(cfg, pa["attn"], h_exit, ctx, ca)
+                       if backfill else ca)
+                k_bf, v_bf = (src[n][:, slot:slot + 1] for n in ("k", "v"))
+                sel = runs[:, None, None, None]
+                want_k = jnp.where(sel, k_run, k_bf)
+                want_v = jnp.where(sel, v_run, v_bf)
+            np.testing.assert_allclose(np.asarray(got["k"][l, :, slot]),
+                                       np.asarray(want_k[:, 0]),
+                                       rtol=1e-5, atol=1e-5)
+            np.testing.assert_allclose(np.asarray(got["v"][l, :, slot]),
+                                       np.asarray(want_v[:, 0]),
+                                       rtol=1e-5, atol=1e-5)
+            h, _, _ = BLOCKS["dense"].apply(cfg, pa, h, ctx, ca)
+        if si == 0:
+            h_exit = h
+        else:
+            h_exit = jnp.where(runs[:, None, None], h, h_exit)
+        # every other slot keeps its bytes
+        keep = np.arange(W) != slot
+        for n in ("k", "v"):
+            np.testing.assert_array_equal(np.asarray(got[n])[:, :, keep],
+                                          np.asarray(ring[n])[:, :, keep])
+
+    # the per-layer ring path (the whole ring through the scan's outputs)
+    monkeypatch.setattr(model_mod, "in_place_ring", lambda block, ctx: False)
+    _, _, d_ring, cache_ring, ran_ring = _one_step(cfg, params, toks, W)
+    np.testing.assert_array_equal(ran, ran_ring)
+    for a, b in ((d.prediction, d_ring.prediction),
+                 (d.exit_index, d_ring.exit_index),
+                 (d.confidence, d_ring.confidence)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for a, b in zip(jax.tree_util.tree_leaves(cache),
+                    jax.tree_util.tree_leaves(cache_ring)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

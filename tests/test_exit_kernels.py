@@ -437,6 +437,28 @@ def test_cohort_scatter_decode_bit_identical(tiny3):
     _caches_equal(c0, c1)
 
 
+def test_cohort_scatter_decode_bit_identical_recurrent():
+    """Dense rings re-join only their decode rows; the cohort scatter
+    still re-joins what comes back whole, here zamba's mamba states —
+    streams and cache bytes must not move with it on."""
+    cfg = reduced(get_config("zamba2-1.2b"), n_layers=4).replace(
+        dtype="float32", use_kernels=True).with_cascade(
+        n_components=3, exit_boundaries=(1, 2), n_cohorts=2,
+        thresholds=(0.5, 0.5, 0.0), confidence="exit_kernels_parity",
+        exit_mode="cond_batch", cohort_layout="major")
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    on = cfg.with_kernel_tune(cohort_scatter=True)
+    o0, s0, c0 = _decode_trace(model, params, cfg)
+    o1, s1, c1 = _decode_trace(model, params, on)
+    for a, b in zip(o0, o1):
+        for x, y in zip(a, b):
+            np.testing.assert_array_equal(x, y)
+    np.testing.assert_array_equal(np.asarray(s0.segments_run),
+                                  np.asarray(s1.segments_run))
+    _caches_equal(c0, c1)
+
+
 @pytest.fixture(scope="module")
 def eng_params():
     cfg = reduced(get_config("qwen2.5-3b")).replace(dtype="float32")

@@ -15,6 +15,7 @@ different tests.  Compiles for a described chip cannot be read back from
 the persistent compilation cache, so the cache is off around them.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -192,3 +193,110 @@ def test_full_width_decode_loop_with_kernels_fits_one_chip(one_chip):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert used < CHIP_HBM_BYTES, mem
+
+
+def _computations(hlo: str):
+    """{name: [instruction lines]} of an HLO module's text, and the name
+    of its entry computation."""
+    comps, cur, entry = {}, None, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            cur = head.group(2)
+            comps[cur] = []
+            if head.group(1):
+                entry = cur
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            comps[cur].append(line.strip())
+    return comps, entry
+
+
+def _callees(line: str):
+    names = re.findall(r"(?:body|condition|calls|to_apply)=%?([\w.\-]+)",
+                       line)
+    for group in re.findall(r"branch_computations=\{([^}]*)\}", line):
+        names += re.findall(r"%?([\w.\-]+)", group)
+    return names
+
+
+def _loop_body(comps, entry):
+    """Every computation the decode loop's ``while`` body reaches (its
+    segment scans, branches and fusions): the work of one decode step."""
+    loops = [_callees(l) for l in comps[entry] if " while(" in l]
+    assert len(loops) == 1, "expected one decode-loop while in the entry"
+    seen, todo = set(), [n for n in loops[0]]
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            todo += _callees(line)
+    return seen
+
+
+def _instr(line: str):
+    """(name, result shape dims, opcode, operand names) of one HLO
+    instruction with an array result, else None."""
+    m = re.match(r"(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w\-]+)\((.*)",
+                 line)
+    if m is None:
+        return None
+    dims = tuple(int(d) for d in m.group(2).split(",") if d)
+    return (m.group(1), dims, m.group(3),
+            re.findall(r"%([\w.\-]+)", m.group(4).split(")")[0]))
+
+
+@pytest.mark.parametrize("n_cohorts", [1, 2])
+def test_decode_loop_writes_cache_rows_in_place(one_chip, n_cohorts):
+    """The full-width qwen2.5-3b XLA decode loop (lane batch 8, 1024-slot
+    ring, cond_batch) writes each step's K/V into the stacked segment
+    caches one row per layer, in place: no copy of a whole stacked cache
+    runs inside the loop, and every update of one writes a slice one
+    position wide along W."""
+    from repro.launch.steps import make_decode_loop_step, make_decode_state
+    from repro.models.model import build_model
+
+    B, W, chunk = 8, 1024, 8
+    cfg = get_config("qwen2.5-3b").replace(use_kernels=False).with_cascade(
+        thresholds=(0.5, 0.5, 0.0), exit_mode="cond_batch",
+        n_cohorts=n_cohorts)
+    model = build_model(cfg)
+    step = make_decode_loop_step(model, cfg, chunk, W)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = on_chip(jax.eval_shape(lambda: model.init_cache(B, W)))
+    state = on_chip(jax.eval_shape(lambda: make_decode_state(cfg, B)))
+    token = jax.ShapeDtypeStruct((B, 1), I32, sharding=one_chip)
+    remaining = jax.ShapeDtypeStruct((B,), I32, sharding=one_chip)
+    hlo = jax.jit(step, donate_argnums=(2, 3)).lower(
+        params, token, cache, state, remaining, None).compile().as_text()
+
+    stacked = {tuple(leaf.shape) for leaf in
+               jax.tree_util.tree_leaves(cache["segments"])}
+    assert stacked == {(12, B, W, KV, HD)}
+    comps, entry = _computations(hlo)
+    copies, updates = [], []
+    for name in _loop_body(comps, entry):
+        shapes = {}
+        for line in comps[name]:
+            ins = _instr(line)
+            if ins is None:
+                continue
+            iname, dims, op, operands = ins
+            shapes[iname] = dims
+            if dims in stacked and op == "copy":
+                copies.append(iname)
+            if dims in stacked and op == "dynamic-update-slice":
+                updates.append((iname, shapes.get(operands[1])))
+    assert not copies, f"whole stacked caches copied per step: {copies}"
+    assert updates, "no in-place update of a stacked cache in the loop"
+    wide = [(n, s) for n, s in updates if s is None or s[2] != 1]
+    assert not wide, f"stacked-cache updates wider than one slot: {wide}"

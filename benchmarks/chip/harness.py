@@ -1,7 +1,9 @@
 """One run of one benchmark cell: load, warm up, measure, check.
 
 Everything a cell needs is found by name: the cell in ``BENCHMARK.json``,
-its configuration in ``configs/<name>.json``, its traffic mix in
+its configuration in ``configs/<name>.json`` (which names its program
+builder in ``programs/``, its reference in ``references/`` and its work
+arithmetic), its traffic mix in
 ``traffic/<name>.json`` (which names a module in ``generators/``), its
 output limits in ``limits/<cell>.json``, per-layer metrics as
 ``metrics/<name>.py`` and the chip's peaks in ``peaks.json``.
@@ -52,7 +54,7 @@ def _json(path: str) -> dict:
 
 def load_module(path: str, name: str):
     spec = importlib.util.spec_from_file_location(name, path)
-    if spec is None:
+    if spec is None or not os.path.exists(path):
         raise SetupError(f"cannot load {path}")
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
@@ -120,29 +122,14 @@ def percentile(values, q: float) -> Optional[float]:
 # ---------------------------------------------------------------------------
 
 def program_config(cell: Cell, thresholds):
-    from repro.configs.base import (CascadeConfig, ModelConfig, ObsConfig,
-                                    PagedCacheConfig)
-    c, srv = cell.config, cell.config["serving"]
-    cas = c["cascade"]
-    return ModelConfig(
-        name=c["name"], family="dense",
-        n_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        n_heads=c["num_attention_heads"],
-        n_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
-        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-        qkv_bias=bool(c["attention_bias"]), rope_theta=c["rope_theta"],
-        norm_eps=c["rms_norm_eps"],
-        tie_embeddings=bool(c["tie_word_embeddings"]),
-        dtype=c["torch_dtype"], use_kernels=bool(srv["use_kernels"]),
-        source=c["source"],
-        cascade=CascadeConfig(
-            n_components=cas["n_components"],
-            exit_boundaries=tuple(cas["exit_boundaries"]),
-            thresholds=tuple(float(t) for t in thresholds) + (0.0,),
-            confidence=cas["confidence"], exit_mode=cas["exit_mode"],
-            n_cohorts=int(cell.traffic.get("n_cohorts", 1))),
-        paged_cache=PagedCacheConfig(layout=srv["cache_layout"]),
-        obs=ObsConfig(enabled=True, max_events=int(srv["max_events"])))
+    """The program's ``ModelConfig`` for ``cell`` at the intermediate
+    ``thresholds``, from the builder its configuration file names
+    (``programs/<program>.py``, ``dense_gqa`` where it names none)."""
+    name = cell.config.get("program", "dense_gqa")
+    prog = load_module(os.path.join(HERE, "programs", name + ".py"),
+                       "program_" + name)
+    return prog.model_config(cell.config, thresholds,
+                             int(cell.traffic.get("n_cohorts", 1)))
 
 
 # ---------------------------------------------------------------------------
